@@ -17,6 +17,11 @@ import pathlib
 import statistics
 import time
 
+from repro.compile_cache import use_compile_cache
+
+# every benchmark imports this module before its first compile
+use_compile_cache()
+
 RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "paper"
 
 MS = 1_000_000.0

@@ -9,9 +9,9 @@ Acceptance targets:
   * ISSUE 3: the million-flow scaling curve (`--scaling` / `--smoke`):
     flow-epochs/s at n_flows in {1k, 10k, 100k, 1M} for the compiled
     RouteLayout path, the original `.at[].add` scatter path, and the
-    shard_map'd flow axis (subprocess with
-    --xla_force_host_platform_device_count; the device count must be fixed
-    before jax initializes).
+    shard_map'd flow axis (in-process over the host's devices; on a CPU
+    a child with --xla_force_host_platform_device_count, since the device
+    count must be fixed before jax initializes).
   * ISSUE 4: the sharded flow axis runs under the locality ShardPlan
     ("sharded2-local": private links reduced on-shard, only the boundary
     tail psummed) next to the PR-3 full-buffer exchange ("sharded2");
@@ -241,12 +241,12 @@ def _scenario(n_flows: int, multipath: bool, kind: str = "dumbbell",
 def _dump_scenario(n_flows: int, kind: str = "dumbbell",
                    k: int = 8) -> pathlib.Path:
     """Publish the compiled scenario to the content-addressed bundle cache
-    so the sharded subprocess can load it — it must not rebuild the same
-    route tensor the parent already compiled (at 1M flows that is most of
-    the wall time).  Dumbbell points ship the single-path scenario;
-    fat-tree points ship the full multipath one plus its locality tiers
-    (and LbParams when present) so the subprocess reproduces the
-    pod-locality plan.  The bundle is keyed by the bench build request,
+    so the sharded run (in this process or a forced-device child) can
+    load it — it must not rebuild the same route tensor already compiled
+    (at 1M flows that is most of the wall time).  Dumbbell points ship
+    the single-path scenario; fat-tree points ship the full multipath one
+    plus its locality tiers (and LbParams when present) so the sharded
+    run reproduces the pod-locality plan.  The bundle is keyed by the bench build request,
     so repeat runs on one host dedupe to a single write (atomic rename —
     concurrent runs race safely) and later processes skip the build."""
     from repro.fleetsim import service
@@ -330,45 +330,70 @@ def _fat_tree_layout_point(ft_k: int, ft_n: int, ft_ne: int, *,
         n_flow_paths=int(np.prod(fast_net.routes.shape[:2])))
 
 
-def _sharded_point(n_flows: int, n_epochs: int, n_devices: int = 2,
-                   locality: bool = True, kind: str = "dumbbell",
-                   k: int = 8) -> dict:
-    """Time the shard_map'd flow axis in a subprocess (the forced host
-    device count must be set before jax initializes).  Returns warm_s
-    plus the plan's boundary stats.  The compiled scenario is loaded
-    from the parent's content-addressed bundle, not rebuilt; fat-tree
-    points also load the locality tiers (pod-grouped plan) and the
-    adaptive LbParams.  The dense RouteLayout rides in the bundle but is
-    stripped before sharding — each shard compiles its own local view."""
-    scn = _dump_scenario(n_flows, kind, k)
+def _sharded_run(bundle: str, n_devices: int, n_epochs: int, *,
+                 locality: bool = True, dc: bool = False) -> dict:
+    """Time the sharded steady state of a published scenario bundle over
+    the first `n_devices` local devices.  Returns warm_s plus the plan's
+    boundary stats.  The dense RouteLayout rides in the bundle but is
+    stripped before sharding — each shard compiles its own local view.
+    `dc=True` shards DC-major (shard == datacenter) with the neighbor
+    halo exchange where the plan allows it."""
+    from repro.fleetsim import service
+    from repro.fleetsim.shard import (flow_mesh, shard_scenario,
+                                      steady_state_prepared)
+    fs = service.load_bundle(bundle)
+    if fs is None:
+        raise RuntimeError(f"scenario bundle missing or corrupt: {bundle}")
+    dc_kw = (dict(link_dc=fs.link_dc, exchange="auto", seed=fs.seed)
+             if dc else {})
+    sf = shard_scenario(fs.net._replace(layout=None), fs.params,
+                        is_inter=fs.is_inter, lb=fs.lb, locality=locality,
+                        link_tier=fs.link_tier, mesh=flow_mesh(n_devices),
+                        **dc_kw)
+    kw = dict(n_warm=n_epochs - 10, n_meas=10)
+    _, r = steady_state_prepared(sf, **kw)
+    jax.block_until_ready(r)
+    best = float("inf")
+    for _ in range(2):
+        t0 = time.time()
+        _, r = steady_state_prepared(sf, **kw)
+        jax.block_until_ready(r)
+        best = min(best, time.time() - t0)
+    return {"warm_s": best, "n_links": int(sf.plan.n_links),
+            "n_boundary": int(sf.plan.n_boundary),
+            "nbr_width": None if sf.nbr is None else int(sf.nbr.shape[2])}
+
+
+def _shard_skip_reason(n_devices: int):
+    """Why a sharded point cannot run here, or None.  A CPU run forks a
+    child with forced host devices; an accelerator host shards over its
+    own devices in this process (a chip belongs to the process that
+    touched it first), so it needs `n_devices` of them."""
+    if jax.device_count() >= n_devices or jax.default_backend() == "cpu":
+        return None
+    return (f"needs {n_devices} devices, host has {jax.device_count()} "
+            f"{jax.default_backend()}")
+
+
+def _sharded(bundle, n_devices: int, n_epochs: int, **kw) -> dict:
+    """`_sharded_run` in this process when it sees `n_devices` devices,
+    else (CPU only) in a child with that many forced host devices."""
+    if jax.device_count() >= n_devices:
+        return _sharded_run(str(bundle), n_devices, n_epochs, **kw)
     code = f"""
 import os
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count={n_devices} "
     + os.environ.get("XLA_FLAGS", ""))
-import json, time, jax
-from repro.fleetsim import service
-from repro.fleetsim.shard import shard_scenario, steady_state_prepared
-fs = service.load_bundle({str(scn)!r})
-assert fs is not None, "scenario bundle missing or corrupt: " + {str(scn)!r}
-sf = shard_scenario(fs.net._replace(layout=None), fs.params,
-                    is_inter=fs.is_inter, lb=fs.lb, locality={locality},
-                    link_tier=fs.link_tier)
-kw = dict(n_warm={n_epochs} - 10, n_meas=10)
-_, r = steady_state_prepared(sf, **kw)
-jax.block_until_ready(r)
-best = float("inf")
-for _ in range(2):
-    t0 = time.time()
-    _, r = steady_state_prepared(sf, **kw)
-    jax.block_until_ready(r)
-    best = min(best, time.time() - t0)
-print(json.dumps({{"warm_s": best, "n_links": int(sf.plan.n_links),
-                   "n_boundary": int(sf.plan.n_boundary)}}))
+import json
+from benchmarks.fleetsim_sweep import _sharded_run
+print(json.dumps(_sharded_run({str(bundle)!r}, {n_devices}, {n_epochs},
+                              **{kw!r})))
 """
+    root = pathlib.Path(__file__).resolve().parents[1]
     env = dict(os.environ)
-    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(root / "src"), str(root), env.get("PYTHONPATH", "")])
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
                          text=True, timeout=1800, env=env)
     if out.returncode != 0:
@@ -382,18 +407,26 @@ _MULTI_DC = {"k": 4, "n_dc": 3, "mesh": "ring", "oversub": 1.0}
 
 
 def _multi_dc_point(mode: str, points: list) -> None:
-    """The N-DC smoke point: a 3-DC k=4 ring sharded DC-major onto 3
-    forced host devices (shard == datacenter), ppermute neighbor halo
-    exchange where the plan proves it legal.  Records the boundary
-    payload plus BOTH shrink factors — full-buffer/psum-tail and
-    psum-tail/ppermute-payload — and fails the run when either falls
-    under its floor (MIN_PSUM_SHRINK["multi_dc"] for the boundary cut;
-    the neighbor exchange must strictly shrink the tail or the DC-major
-    plan has stopped matching the topology)."""
+    """The N-DC point: a 3-DC k=4 ring sharded DC-major onto 3 devices
+    (shard == datacenter), ppermute neighbor halo exchange where the plan
+    proves it legal.  Records the boundary payload plus BOTH shrink
+    factors — full-buffer/psum-tail and psum-tail/ppermute-payload — and
+    fails the run when either falls under its floor
+    (MIN_PSUM_SHRINK["multi_dc"] for the boundary cut; the neighbor
+    exchange must strictly shrink the tail or the DC-major plan has
+    stopped matching the topology), or when the point itself fails."""
     from repro.fleetsim import service
     from repro.scenarios import fingerprint, multi_dc_spec, to_fleetsim
     n = 15_000 if mode == "smoke" else 60_000
     ne = 300 if mode == "smoke" else 200
+    variant = f"multi_dc_k{_MULTI_DC['k']}"
+    skip = _shard_skip_reason(_MULTI_DC["n_dc"])
+    if skip:
+        rec = {"n_flows": n, "n_epochs": ne, "variant": variant,
+               "path": "sharded3-nbr", "skipped": True, "reason": skip}
+        points.append(rec)
+        print("  ", json.dumps(rec))
+        return
     key = fingerprint({"bench_scenario": "fleetsim_sweep",
                        "kind": "multi_dc", "n_flows": n, **_MULTI_DC},
                       service.CACHE_VERSION)
@@ -404,55 +437,16 @@ def _multi_dc_point(mode: str, points: list) -> None:
                                        **_MULTI_DC))
         path = service.publish_scenario(fs, key)
         print(f"   multi_dc spec build {time.time() - t0:.1f}s")
-    code = f"""
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count={_MULTI_DC['n_dc']} "
-    + os.environ.get("XLA_FLAGS", ""))
-import json, time, jax
-from repro.fleetsim import service
-from repro.fleetsim.shard import shard_scenario, steady_state_prepared
-fs = service.load_bundle({str(path)!r})
-assert fs is not None, "scenario bundle missing or corrupt: " + {str(path)!r}
-sf = shard_scenario(fs.net._replace(layout=None), fs.params,
-                    is_inter=fs.is_inter, lb=fs.lb,
-                    link_tier=fs.link_tier, link_dc=fs.link_dc,
-                    exchange="auto", seed=fs.seed)
-kw = dict(n_warm={ne} - 10, n_meas=10)
-_, r = steady_state_prepared(sf, **kw)
-jax.block_until_ready(r)
-best = float("inf")
-for _ in range(2):
-    t0 = time.time()
-    _, r = steady_state_prepared(sf, **kw)
-    jax.block_until_ready(r)
-    best = min(best, time.time() - t0)
-print(json.dumps({{
-    "warm_s": best, "n_links": int(sf.plan.n_links),
-    "n_boundary": int(sf.plan.n_boundary),
-    "nbr_width": None if sf.nbr is None else int(sf.nbr.shape[2])}}))
-"""
-    env = dict(os.environ)
-    src = str(pathlib.Path(__file__).resolve().parents[1] / "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     try:
-        out = subprocess.run([sys.executable, "-c", code],
-                             capture_output=True, text=True, timeout=1800,
-                             env=env)
-        if out.returncode != 0:
-            raise RuntimeError(out.stderr[-2000:])
-        res = json.loads(out.stdout.strip().splitlines()[-1])
+        res = _sharded(path, _MULTI_DC["n_dc"], ne, dc=True)
     except (RuntimeError, subprocess.TimeoutExpired, OSError,
             json.JSONDecodeError, KeyError, IndexError) as e:
-        if mode == "smoke":
-            raise SystemExit("multi_dc smoke point failed: " + str(e)[:500])
-        print("   multi_dc point failed:", str(e)[:200])
-        return
+        raise SystemExit("multi_dc point failed: " + str(e)[:500])
     full_payload = res["n_links"] + 1
     psum_shrink = full_payload / max(res["n_boundary"], 1)
     width = res["nbr_width"]
     nbr_shrink = (res["n_boundary"] / (2 * width)) if width else None
-    rec = _point(n, ne, variant=f"multi_dc_k{_MULTI_DC['k']}",
+    rec = _point(n, ne, variant=variant,
                  path="sharded3-nbr", warm_s=res["warm_s"],
                  topology=dict(_MULTI_DC),
                  n_links=res["n_links"], n_boundary=res["n_boundary"],
@@ -711,7 +705,7 @@ def _append_history(entry: dict) -> None:
         {"schema": "trajectory-v1", "history": hist}, indent=1))
 
 
-def _sharded_points(n: int, ne: int, mode: str, points: list,
+def _sharded_points(n: int, ne: int, points: list,
                     speedups: dict, kind: str = "dumbbell", k: int = 8,
                     variant: str = "single",
                     paths=(("sharded2-local", True),
@@ -722,37 +716,31 @@ def _sharded_points(n: int, ne: int, mode: str, points: list,
     overhead dominates and the curve stops measuring aggregation.  The
     locality point's boundary-psum payload shrink is guarded per scenario
     kind (MIN_PSUM_SHRINK) — the dumbbell's 2-link boundary warrants 10x,
-    a fat-tree's agg/core/WAN cut ~1.5x.  In smoke mode a FAILED locality
-    point is fatal: CI's payload guard must not pass vacuously because
-    the subprocess crashed."""
+    a fat-tree's agg/core/WAN cut ~1.5x.  A FAILED point is fatal in
+    every mode: the payload guard must not pass vacuously because the
+    sharded run crashed."""
     n_devices = 2
     sh_ne = min(ne, 300)
     per_shard = n // n_devices
     min_shrink = MIN_PSUM_SHRINK[kind]
+    skip = _shard_skip_reason(n_devices)
+    if per_shard < MIN_SHARD_FLOWS:
+        skip = f"flows_per_shard {per_shard} < {MIN_SHARD_FLOWS}"
     rates = {}
     for path_name, locality in paths:
-        if per_shard < MIN_SHARD_FLOWS:
+        if skip:
             rec = {"n_flows": n, "n_epochs": sh_ne, "variant": variant,
-                   "path": path_name, "skipped": True,
-                   "reason": f"flows_per_shard {per_shard} < "
-                             f"{MIN_SHARD_FLOWS}"}
+                   "path": path_name, "skipped": True, "reason": skip}
             points.append(rec)
             print("  ", json.dumps(rec))
             continue
         try:
-            res = _sharded_point(n, sh_ne, n_devices, locality=locality,
-                                 kind=kind, k=k)
+            res = _sharded(_dump_scenario(n, kind, k), n_devices, sh_ne,
+                           locality=locality)
         except (RuntimeError, subprocess.TimeoutExpired, OSError,
                 json.JSONDecodeError, KeyError, IndexError) as e:
-            if mode == "smoke" and locality:
-                raise SystemExit(
-                    f"locality-sharded smoke point failed at n={n}: "
-                    + str(e)[:500])
-            # outside smoke, keep the rest of the curve (and still write
-            # the JSON) if the sharded subprocess hangs, dies, or prints
-            # garbage
-            print(f"  {path_name} point failed:", str(e)[:200])
-            continue
+            raise SystemExit(f"{path_name} point failed at n={n}: "
+                             + str(e)[:500])
         rec = _point(n, sh_ne, variant=variant, path=path_name,
                      warm_s=res["warm_s"])
         rates[path_name] = rec["flow_epochs_per_s"]
@@ -814,7 +802,7 @@ def scaling_curve(mode: str = "full", *, backend: str = "auto",
             speedups[f"{variant}:{n}"] = round(
                 (n * ne / warm) / (n * ref_ne / ref_warm), 2)
         # sharded flow axis (2 CPU shards; single-path scenario)
-        _sharded_points(n, ne, mode, points, speedups)
+        _sharded_points(n, ne, points, speedups)
 
     # fat-tree points (the paper's actual topology — PAPER §5.1): the
     # pod-structured permutation/inter mix at FAT_TREE_PATHS ECMP paths,
@@ -830,7 +818,7 @@ def scaling_curve(mode: str = "full", *, backend: str = "auto",
                                          profile_dir=profile_dir))
     ft_paths = ((("sharded2-local", True),) if mode == "smoke" else
                 (("sharded2-local", True), ("sharded2", False)))
-    _sharded_points(ft_n, ft_ne, mode, points, speedups, kind="fat_tree",
+    _sharded_points(ft_n, ft_ne, points, speedups, kind="fat_tree",
                     k=ft_k, variant=variant, paths=ft_paths)
 
     # multi-DC point (the N-datacenter topology layer): 3-DC k=4 ring,

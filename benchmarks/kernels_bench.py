@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks import common
+from repro.fleetsim.links import hop_major_idx
 from repro.kernels import fleet_pallas, ops, ref
 
 
@@ -80,7 +81,7 @@ def _fleet_kernels(rng, n=1024, p=4, h=5, n_links=64) -> dict:
     instead — see the module docstring)."""
     routes = rng.integers(-1, n_links, size=(n, p, h)).astype(np.int32)
     routes[:, 0, 0] = rng.integers(0, n_links, size=n)
-    pad_idx = jnp.asarray(np.where(routes >= 0, routes, n_links))
+    hop_idx = hop_major_idx(jnp.asarray(routes), n_links)
     sub = jnp.asarray(rng.uniform(0, 1, (n, p)).astype(np.float32))
     scale = jnp.asarray(rng.uniform(0.1, 2.0, n_links + 1), jnp.float32)
     frac = jnp.asarray(rng.uniform(0, 1, n_links + 1), jnp.float32)
@@ -90,9 +91,9 @@ def _fleet_kernels(rng, n=1024, p=4, h=5, n_links=64) -> dict:
     res = {"n_flows": n, "picked_block": picked}
     for label, blk in (("picked", None), ("fixed512", 512)):
         t_s = _time(lambda pi, s: fleet_pallas.link_scatter(
-            pi, s, n_links, block=blk), pad_idx, sub)
+            pi, s, n_links, block=blk), hop_idx, sub)
         t_g = _time(lambda pi, a, b, c: fleet_pallas.link_gathers(
-            pi, a, b, c, block=blk), pad_idx, scale, frac, delay)
+            pi, a, b, c, n, block=blk), hop_idx, scale, frac, delay)
         res[f"link_scatter_{label}_ms"] = round(t_s * 1e3, 2)
         res[f"link_gathers_{label}_ms"] = round(t_g * 1e3, 2)
     return res

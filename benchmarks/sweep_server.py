@@ -67,6 +67,7 @@ import time
 
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.fleetsim import service, sweeps
 
 # end-to-end cold/warm floor per bench mode (CI gate; env-overridable for
@@ -119,7 +120,7 @@ def serve(args) -> int:
 
 # ------------------------------------------------------------- benchmark
 
-def _drain_whatifs(fs, factors):
+def drain_whatifs(fs, factors):
     """Shape-compatible what-if cells: the same compiled scenario with the
     phantom drain target scaled per cell (a capacity-planning knob)."""
     return [(fs.net._replace(drain=fs.net.drain * f), fs.params,
@@ -203,7 +204,7 @@ def bench(mode: str, cache_dir=None, refresh_floor=None) -> dict:
 
     # 4-query what-if batch: one rung-4 executable, at most one new trace
     whatifs = [service.SweepQuery(c, **cfg) for c in
-               _drain_whatifs(fs, (0.80, 0.85, 0.90, 0.95))]
+               drain_whatifs(fs, (0.80, 0.85, 0.90, 0.95))]
     tr0 = sweeps.grid_traces()
     t0 = time.time()
     svc.submit(whatifs)
@@ -326,6 +327,7 @@ def _main() -> int:
                     help="with --bench: CI scale (k=4 / 12k flows) "
                          "instead of k=8 / 100k")
     args = ap.parse_args()
+    use_compile_cache()
     if args.bench:
         bench("smoke" if args.smoke else "full", cache_dir=args.cache_dir)
         return 0

@@ -28,7 +28,7 @@ from repro.core.window_scheduler import (ChunkWindowScheduler,  # noqa: E402
 
 
 def main() -> None:
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = sharding.make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = reduced(get_config("granite-8b"), n_layers=4, d_model=128, d_ff=512)
     run = RunConfig(uno_chunks=8)
     print(f"mesh {dict(zip(mesh.axis_names, mesh.devices.shape))}, "

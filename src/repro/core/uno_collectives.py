@@ -42,7 +42,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.kernels import ops, ref
-from repro.sharding import shard_map
 
 F32 = jnp.float32
 
@@ -218,7 +217,7 @@ def make_uno_grad_sync(mesh: Mesh, cfg: ModelConfig, run: RunConfig
         def exchange_local(vloc):                  # (1, N_local) on-device
             return _pod_ring_psum(vloc[0], run, n_pods)
 
-        exchange = shard_map(
+        exchange = jax.shard_map(
             exchange_local, mesh=mesh,
             in_specs=P("pod", inpod_axes), out_specs=P(inpod_axes),
             axis_names=set(all_axes), check_vma=False)
@@ -269,7 +268,7 @@ def make_uno_grad_sync(mesh: Mesh, cfg: ModelConfig, run: RunConfig
                 off += n
             return jax.tree.unflatten(jax.tree.structure(tree_loc), res)
 
-        exchange = shard_map(
+        exchange = jax.shard_map(
             local_fn, mesh=mesh, in_specs=(in_specs,), out_specs=out_specs,
             axis_names=set(all_axes), check_vma=False)
         return exchange(stacked)

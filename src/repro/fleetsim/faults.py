@@ -210,7 +210,7 @@ def degrade_split(net: L.FluidNet, split: jnp.ndarray, cap_scale,
     link resumes with the pre-fault weights instantly.
     """
     cs = jnp.concatenate([cap_scale, jnp.ones(1, cap_scale.dtype)])
-    alive = jnp.min(cs[L._pad_idx(net)], axis=2) > 0.0
+    alive = L.hop_reduce(net, cs, jnp.min) > 0.0
     ok = pmask & alive
     any_alive = jnp.any(ok, axis=1)
     w = jnp.where(ok, split, 0.0)

@@ -9,9 +9,11 @@ the last path).  Everything the per-epoch hot path needs from that tensor is
 (`compute_layout` / `with_layout`, attached by the scenario compiler in
 repro.scenarios.compile_fleetsim):
 
-  * `pad_idx` / `hop_mask` / `path_mask` — the -1-redirected hop indices and
-    validity masks every gather consumes (previously re-derived four times
-    per epoch inside the `lax.scan` body);
+  * `hop_idx` / `path_mask` — the -1-redirected hop indices, hop-major
+    and lane-padded (`hop_major_idx`; the per-subflow hop reductions and
+    the Pallas kernels read it), and the path validity mask every gather
+    consumes (previously re-derived four times per epoch inside the
+    `lax.scan` body);
   * a by-link-sorted CSR view of the incidence — `sort_sub` (which subflow
     each route entry belongs to), `sort_link` (its link, ascending),
     `link_ptr` (CSR segment offsets), and `csr_gather` (the same order
@@ -59,7 +61,8 @@ backends selected by `backend=`:
     is attached.  "auto" selects it whenever the table is present.
   * "pallas" / "pt_pallas" — repro.kernels.fleet_pallas runs the flat
     (respectively path-table) scatter and the link->flow gathers as
-    blocked one-hot-matmul kernels (interpret mode on CPU).
+    blocked one-hot-matmul kernels (compiled on a TPU, the Pallas
+    interpreter elsewhere — repro.kernels.interpret_mode decides).
 
 `offered_load(..., axis_name=...)` psums the per-shard partial loads, which
 is all `repro.fleetsim.shard` needs to run the flow axis under `shard_map`.
@@ -151,8 +154,8 @@ class RouteLayout(NamedTuple):
     All arrays are int32/bool and constant across epochs — compute once per
     scenario (`compute_layout`), thread through FluidNet.
     """
-    pad_idx: jnp.ndarray     # (n, p, h) hop link ids, -1 -> L (scratch slot)
-    hop_mask: jnp.ndarray    # (n, p, h) bool: True on real hops
+    hop_idx: jnp.ndarray     # (h, p, m >= n) hop link ids, -1 -> L (scratch
+                             # slot); lanes past n_flows -> L (hop_major_idx)
     path_mask: jnp.ndarray   # (n, p) bool: True on real paths
     sort_sub: jnp.ndarray    # (E,) subflow id per by-link-sorted entry; pads -> S
     sort_link: jnp.ndarray   # (E,) ascending link id per entry; pads -> L
@@ -214,11 +217,43 @@ def _routes3(net: FluidNet) -> jnp.ndarray:
 
 
 def _pad_idx(net: FluidNet) -> jnp.ndarray:
-    """Hop indices with -1 redirected to the scratch slot n_links."""
-    if net.layout is not None:
-        return net.layout.pad_idx
+    """(n, p, h) hop indices with -1 redirected to the scratch slot
+    n_links (the reference scatter's flow-major view)."""
     r = _routes3(net)
     return jnp.where(r >= 0, r, net.n_links)
+
+
+def hop_major_idx(routes: jnp.ndarray, n_links: int) -> jnp.ndarray:
+    """(max_hops, n_paths, m) int32 hop-major route index, flows on the
+    minor (lane) axis: -1 hops and the lanes past n_flows hold the
+    scratch slot n_links, and m = `fleet_pallas.pad_lanes(n_flows)` is a
+    whole number of the Pallas kernels' flow blocks."""
+    from repro.kernels.fleet_pallas import pad_lanes
+    r = routes if routes.ndim == 3 else routes[:, None, :]
+    n = r.shape[0]
+    idx = jnp.transpose(jnp.where(r >= 0, r, n_links).astype(jnp.int32),
+                        (2, 1, 0))
+    return jnp.pad(idx, ((0, 0), (0, 0), (0, pad_lanes(n) - n)),
+                   constant_values=n_links)
+
+
+def _hop_idx(net: FluidNet) -> jnp.ndarray:
+    if net.layout is not None:
+        return net.layout.hop_idx
+    return hop_major_idx(net.routes, net.n_links)
+
+
+def hop_reduce(net: FluidNet, vals: jnp.ndarray, reduce) -> jnp.ndarray:
+    """(n_flows, n_paths) `reduce` over each subflow's hops of the
+    (n_links + 1,) per-link `vals` (the last slot is what padding hops
+    read).
+
+    Gathers through the hop-major index, flows on the minor axis: the
+    TPU compiler builds that gather in about a second at any fleet size,
+    while the same gather through a flow-major (n, p, h) index takes it
+    minutes at 100k+ flows."""
+    n = net.routes.shape[0]
+    return reduce(vals[_hop_idx(net)[..., :n]], axis=0).T
 
 
 def _blocked_csr(sort_key: np.ndarray, sort_val: np.ndarray, n_keys: int,
@@ -409,7 +444,7 @@ def compute_layout(routes: jnp.ndarray, n_links: int, *,
     else:
         raise ValueError(f"path_table={path_table!r}: expected 'auto', "
                          "True, False/None, or a PathTable")
-    return RouteLayout(pad_idx=pad_idx, hop_mask=hop_mask,
+    return RouteLayout(hop_idx=hop_major_idx(routes, n_links),
                        path_mask=path_mask, sort_sub=sort_sub,
                        sort_link=sort_link, link_ptr=link_ptr,
                        csr_gather=csr_gather, path_table=pt)
@@ -438,7 +473,7 @@ def layout_from_arrays(arrays, prefix: str = "lay_") -> \
     """Inverse of `layout_to_arrays`; `arrays` is any mapping (e.g. an
     open NpzFile).  Returns None when no layout was serialized — the
     round trip preserves "no layout" as well as flat vs PathTable'd."""
-    if prefix + "pad_idx" not in arrays:
+    if prefix + "hop_idx" not in arrays:
         return None
     pt = None
     if prefix + "pt_pre_id" in arrays:
@@ -500,23 +535,21 @@ def _offered_load_reference(net: FluidNet, rates, split) -> jnp.ndarray:
 def _offered_load_segment(net: FluidNet, rates, split) -> jnp.ndarray:
     """jax.ops.segment_sum over the by-link-sorted layout."""
     lay = net.layout
-    sub = jnp.concatenate([(rates[:, None] * split).reshape(-1),
-                           jnp.zeros(1, rates.dtype)])
-    vals = sub[lay.sort_sub]
+    vals = _take_subflows(rates, split, lay.sort_sub)
     return jax.ops.segment_sum(vals, lay.sort_link,
                                num_segments=net.n_links + 1,
                                indices_are_sorted=True)
 
 
-def _blocked_segment_sum(vals_ext: jnp.ndarray, gather: jnp.ndarray,
+def _blocked_segment_sum(entries: jnp.ndarray,
                          ptr: jnp.ndarray) -> jnp.ndarray:
-    """(len(ptr) - 1,) segment totals of the sorted entries `vals_ext[gather]`.
+    """(len(ptr) - 1,) segment totals of the sorted `entries`.
 
-    `gather` is an (n_chunks, block) row-per-chunk matrix of entry ids
-    into `vals_ext`, whose LAST slot must hold 0.0 (the block-padding
-    sentinel reads it); `ptr` holds each output segment's CSR offsets in
-    the underlying sorted order.  Entries are gathered chunk-contiguous
-    and prefix-summed along the fast block axis (XLA's native cumsum on
+    `entries` is the (n_chunks, block) row-per-chunk matrix of sorted
+    entry values, gathered through a `_blocked_csr` gather matrix (block
+    padding reads 0.0); `ptr` holds each output segment's CSR offsets in
+    the underlying sorted order.  Entries are chunk-contiguous and
+    prefix-summed along the fast block axis (XLA's native cumsum on
     the contiguous minor axis beats a Hillis-Steele doubling pass here —
     the doubling's log2(block) concatenate copies cost more than they
     save); each segment total is then assembled from CHUNK-LOCAL pieces —
@@ -532,8 +565,8 @@ def _blocked_segment_sum(vals_ext: jnp.ndarray, gather: jnp.ndarray,
     chunk's), so per-segment relative error stays at float32 rounding
     scale.
     """
-    n_chunks, block = gather.shape
-    cs = jnp.cumsum(vals_ext[gather], axis=1)     # chunk-local prefixes
+    n_chunks, block = entries.shape
+    cs = jnp.cumsum(entries, axis=1)              # chunk-local prefixes
     chunk_tot = cs[:, -1]
 
     a = ptr[:-1]                                  # segment starts
@@ -558,18 +591,25 @@ def _blocked_segment_sum(vals_ext: jnp.ndarray, gather: jnp.ndarray,
     return jnp.where(b > a, out, 0.0)
 
 
-def _sub_vals_ext(rates, split) -> jnp.ndarray:
-    """(S + 1,) flattened subflow rates with the 0.0 sentinel appended."""
-    return jnp.concatenate([(rates[:, None] * split).reshape(-1),
-                            jnp.zeros(1, rates.dtype)])
+def _take_subflows(rates, split, ids) -> jnp.ndarray:
+    """rates[f] * split[f, j] at flow-major subflow ids (f * n_paths + j),
+    shaped like `ids`; the block-pad sentinel id n_flows * n_paths reads
+    0.0.  Indexes the (n_flows, n_paths) matrix directly: flattening it
+    is a relayout whose TPU compile time grows with n_flows (minutes at
+    1M flows)."""
+    n, p = split.shape
+    f, j = ids // p, ids % p
+    live = f < n
+    f = jnp.where(live, f, 0)
+    return jnp.where(live, rates[f] * split[f, j], 0.0)
 
 
 def _offered_load_csr(net: FluidNet, rates, split) -> jnp.ndarray:
     """Blocked cumulative-sum segment reduction over the flat sorted layout
     (see `_blocked_segment_sum`); returns the (n_links + 1,) load buffer."""
     lay = net.layout
-    return _blocked_segment_sum(_sub_vals_ext(rates, split),
-                                lay.csr_gather, lay.link_ptr)
+    return _blocked_segment_sum(_take_subflows(rates, split, lay.csr_gather),
+                                lay.link_ptr)
 
 
 def _pt_seg_rates(pt: PathTable, rates, split) -> jnp.ndarray:
@@ -577,8 +617,8 @@ def _pt_seg_rates(pt: PathTable, rates, split) -> jnp.ndarray:
     (every subflow contributes to BOTH its prefix and suffix segment).
     The final slot is the stage-1 block-pad sentinel segment and is
     guaranteed 0.0 — stage 2's own pad entries read it."""
-    return _blocked_segment_sum(_sub_vals_ext(rates, split),
-                                pt.seg_gather, pt.seg_ptr)
+    return _blocked_segment_sum(_take_subflows(rates, split, pt.seg_gather),
+                                pt.seg_ptr)
 
 
 def _offered_load_pt(net: FluidNet, rates, split) -> jnp.ndarray:
@@ -588,7 +628,7 @@ def _offered_load_pt(net: FluidNet, rates, split) -> jnp.ndarray:
     blocked-CSR reduction the flat backend uses."""
     pt = net.layout.path_table
     seg = _pt_seg_rates(pt, rates, split)
-    return _blocked_segment_sum(seg, pt.lcsr_gather, pt.llink_ptr)
+    return _blocked_segment_sum(seg[pt.lcsr_gather], pt.llink_ptr)
 
 
 def _resolve_backend(net: FluidNet, backend: str) -> str:
@@ -683,31 +723,22 @@ def offered_load(net: FluidNet, rates: jnp.ndarray,
     aggregation implementation (see module docstring); "auto" uses the
     PathTable pipeline when the layout carries one, else the blocked-CSR
     path whenever a layout is attached.  `block` overrides the Pallas
-    flow-block size (None picks it from n_flows).
+    subflow-block size (None picks it from the route size); the Pallas
+    backends compile on a TPU and interpret elsewhere
+    (repro.kernels.interpret_mode).
     """
     split = _split_or_uniform(net, split)
     backend = _resolve_backend(net, backend)
-    tiled_halo = halo is not None and 0 < halo < net.n_links
     if backend == "pallas":
         from repro.kernels import fleet_pallas
-        if tiled_halo:
-            priv, bnd = fleet_pallas.link_scatter_tiles(
-                _pad_idx(net), rates[:, None] * split, net.n_links, halo,
-                block=block)
-            buf = jnp.concatenate([priv, bnd])
-        else:
-            buf = fleet_pallas.link_scatter(
-                _pad_idx(net), rates[:, None] * split, net.n_links,
-                block=block)
+        buf = fleet_pallas.link_scatter(
+            _hop_idx(net), rates[:, None] * split, net.n_links, block=block)
     elif backend == "pt_pallas":
         from repro.kernels import fleet_pallas
         pt = net.layout.path_table
         buf = fleet_pallas.path_table_scatter(
             pt.pre_id, pt.suf_id, pt.seg_idx, rates[:, None] * split,
-            net.n_links, n_boundary=halo if tiled_halo else None,
-            block=block)
-        if tiled_halo:
-            buf = jnp.concatenate(buf)
+            net.n_links, block=block)
     elif backend == "pt":
         buf = _offered_load_pt(net, rates, split)
     elif backend == "segment":
@@ -723,9 +754,7 @@ def offered_load(net: FluidNet, rates: jnp.ndarray,
 
 
 # ------------------------------------------------------- link -> flow gathers
-# (one (n, p, h) gather + axis-2 reduce each; XLA CPU fuses the reduce into
-# the gather loop, and A/B runs showed hop-unrolled accumulator variants
-# measurably slower)
+# (one hop-major gather + hop-axis reduce each, through `hop_reduce`)
 
 def subflow_scale(net: FluidNet, load: jnp.ndarray) -> jnp.ndarray:
     """(n_flows, n_paths) goodput/offered ratio: min over hops of cap/load.
@@ -736,7 +765,7 @@ def subflow_scale(net: FluidNet, load: jnp.ndarray) -> jnp.ndarray:
     """
     s = jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS))
     s = jnp.concatenate([s, jnp.ones(1, s.dtype)])   # pad slot: no constraint
-    return jnp.min(s[_pad_idx(net)], axis=2)
+    return hop_reduce(net, s, jnp.min)
 
 
 def bottleneck_scale(net: FluidNet, load: jnp.ndarray,
@@ -778,7 +807,7 @@ def subflow_loss_frac(net: FluidNet, p_drop: jnp.ndarray) -> jnp.ndarray:
     Same hop composition as `subflow_mark_frac`, on the overflow drop
     probabilities instead of the RED marks."""
     keep = jnp.concatenate([1.0 - p_drop, jnp.ones(1, p_drop.dtype)])
-    return 1.0 - jnp.prod(keep[_pad_idx(net)], axis=2)
+    return 1.0 - hop_reduce(net, keep, jnp.prod)
 
 
 def _pt_gathers(net: FluidNet, load, p_link, q_phys):
@@ -824,7 +853,7 @@ def mark_prob(net: FluidNet, q_phys: jnp.ndarray,
 def subflow_mark_frac(net: FluidNet, p_link: jnp.ndarray) -> jnp.ndarray:
     """(n_flows, n_paths) mark fraction: 1 - prod over hops of (1 - p)."""
     clean = jnp.concatenate([1.0 - p_link, jnp.ones(1, p_link.dtype)])
-    return 1.0 - jnp.prod(clean[_pad_idx(net)], axis=2)
+    return 1.0 - hop_reduce(net, clean, jnp.prod)
 
 
 def path_mark_frac(net: FluidNet, p_link: jnp.ndarray,
@@ -842,7 +871,7 @@ def subflow_delay(net: FluidNet, q_phys: jnp.ndarray) -> jnp.ndarray:
     NaN/Inf in the carry (repro.fleetsim.faults)."""
     d = jnp.concatenate([q_phys / jnp.maximum(net.cap, _EPS),
                          jnp.zeros(1, q_phys.dtype)])
-    return jnp.sum(d[_pad_idx(net)], axis=2)
+    return hop_reduce(net, d, jnp.sum)
 
 
 def path_delay(net: FluidNet, q_phys: jnp.ndarray,
@@ -864,7 +893,7 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
     """One epoch of link physics in one call: offered load -> queue step ->
     mark probabilities -> the three link->flow gathers.
 
-    The gathers share one `pad_idx` read per call via the layout; with
+    The gathers share one `hop_idx` read per call via the layout; with
     `backend="pallas"` they run as one fused kernel pass over the route
     tensor (repro.kernels.fleet_pallas.link_gathers), and with the
     PathTable backends ("pt" / "pt_pallas", also what "auto" picks when
@@ -902,9 +931,10 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
     if rb == "pallas":
         from repro.kernels import fleet_pallas
         sub_scale, sub_frac, sub_delay = fleet_pallas.link_gathers(
-            _pad_idx(net),
+            _hop_idx(net),
             jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
-            1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS), block=block)
+            1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS),
+            net.routes.shape[0], block=block)
     elif rb == "pt_pallas":
         from repro.kernels import fleet_pallas
         pt = net.layout.path_table

@@ -78,7 +78,9 @@ from repro.fleetsim.state import ChurnParams, FleetParams, LbParams
 # the "multi_dc" builder joined the registry, and `_home_links` switched
 # to per-flow hub counting (shard plans, and thus any cached plan-derived
 # payloads, differ from v2 for multipath scenarios).
-CACHE_VERSION = 3
+# v4: RouteLayout's one route index is the hop-major, lane-padded
+# `hop_idx` (in place of the flow-major `pad_idx` and `hop_mask`).
+CACHE_VERSION = 4
 
 _META_KEY = "__meta__"
 

@@ -55,10 +55,11 @@ single-device runs agree to float-sum tolerance (reduction order
 changes), which tests/test_fleet_scale.py pins across single-path,
 multipath, lb, and churn scenarios.
 
-On CPU the same code path is exercised with
-`XLA_FLAGS=--xla_force_host_platform_device_count=N` (device count must
-be set before jax initializes, so tests and the benchmark spawn a fresh
-interpreter).
+Meshes come from `flow_mesh` (Auto axis types over explicit devices).  On
+a TPU host one process drives every chip; on CPU the same code path is
+exercised with `XLA_FLAGS=--xla_force_host_platform_device_count=N`
+(device count must be set before jax initializes, so CPU tests and the
+benchmark spawn a fresh interpreter).
 """
 from __future__ import annotations
 
@@ -77,7 +78,7 @@ from repro.fleetsim.faults import FaultCarry, FaultSchedule
 from repro.fleetsim.reliability import _LADDER_SHARED, RelParams, RelState
 from repro.fleetsim.state import (ChurnParams, FleetParams, FleetState,
                                   LbParams, init_state)
-from repro.sharding import shard_map
+from repro.sharding import make_mesh
 
 AXIS = "flows"
 # FleetState fields replicated across flow shards (cc._NON_FLOW_FIELDS
@@ -86,10 +87,12 @@ AXIS = "flows"
 _REPLICATED = ("q_phys", "q_phantom", "key")
 
 
-def flow_mesh(n_devices: Optional[int] = None):
-    """1-D mesh over the first `n_devices` (default: all) local devices."""
-    n = n_devices or len(jax.devices())
-    return jax.make_mesh((n,), (AXIS,))
+def flow_mesh(n_devices: Optional[int] = None, devices=None):
+    """1-D Auto-axis mesh over `devices`, else the first `n_devices`
+    (default: all) local devices."""
+    if devices is None:
+        devices = jax.devices()[:n_devices or len(jax.devices())]
+    return make_mesh((len(devices),), (AXIS,), devices=devices)
 
 
 def _contiguous_plan(n_real: int, n_links: int, n_shards: int):
@@ -423,14 +426,14 @@ def _compiled_impl(mesh, scheme, n_warm, n_meas, backend, halo, unroll,
             q_phantom=jax.lax.psum(
                 jnp.where(own, final.q_phantom, 0.0), AXIS)), rates
 
-    f = shard_map(local, mesh,
-                  in_specs=(_net_spec(has_ploss), lay_spec, param_spec,
-                            _state_spec(has_rel, has_fault), P(AXIS),
-                            lb_spec, churn_spec, cmap_spec, P(AXIS),
-                            rel_spec, fault_spec,
-                            P(AXIS) if has_nbr else None),
-                  out_specs=(_state_spec(has_rel, has_fault), P(AXIS)),
-                  check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(_net_spec(has_ploss), lay_spec, param_spec,
+                                _state_spec(has_rel, has_fault), P(AXIS),
+                                lb_spec, churn_spec, cmap_spec, P(AXIS),
+                                rel_spec, fault_spec,
+                                P(AXIS) if has_nbr else None),
+                      out_specs=(_state_spec(has_rel, has_fault), P(AXIS)),
+                      check_vma=False)
     return jax.jit(f, donate_argnums=(3,))
 
 

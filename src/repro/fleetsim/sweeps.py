@@ -304,7 +304,6 @@ def _run_grid_sharded(scenarios, scheme, n_warm, n_meas, seed, mesh,
     """
     from jax.sharding import PartitionSpec as P
     from repro.fleetsim import shard as sh
-    from repro.sharding import shard_map
 
     norm = [_norm_scenario(s) for s in scenarios]
     for tag, i in (("lb", 3), ("churn", 4), ("rel", 5), ("fault", 6)):
@@ -447,13 +446,13 @@ def _run_grid_sharded(scenarios, scheme, n_warm, n_meas, seed, mesh,
         cmap_spec = P(AXIS)
     state_spec = g(sh._state_spec(rel is not None, fault is not None))
 
-    f = shard_map(local, mesh,
-                  in_specs=(g(sh._net_spec(nets.p_loss is not None)),
-                            lay_spec, param_spec,
-                            state_spec, g(P(AXIS)), lb_spec, churn_spec,
-                            cmap_spec, P(AXIS), rel_spec, fault_spec),
-                  out_specs=(state_spec, g(P(AXIS))),
-                  check_vma=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(g(sh._net_spec(nets.p_loss is not None)),
+                                lay_spec, param_spec,
+                                state_spec, g(P(AXIS)), lb_spec, churn_spec,
+                                cmap_spec, P(AXIS), rel_spec, fault_spec),
+                      out_specs=(state_spec, g(P(AXIS))),
+                      check_vma=False)
     final, rates = jax.jit(f)(nets, sf0.layouts, params, state0, inters,
                               lb, churn, sf0.churn_map, sf0.own, rel,
                               fault)

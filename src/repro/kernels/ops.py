@@ -1,27 +1,16 @@
-"""Public jit'd kernel API — pads/reshapes, picks Pallas vs interpret mode.
+"""Public jit'd kernel API — pads/reshapes to the kernels' tile multiples.
 
-On this CPU container every pallas_call runs with interpret=True (the kernel
-body executes in Python, validating the exact TPU program); on a TPU runtime
-set REPRO_PALLAS_INTERPRET=0 (or rely on the backend auto-detect) to compile
-the real kernels.
+The kernels compile on a TPU and run in the Pallas interpreter on every other
+backend (repro.kernels.interpret_mode decides; the interpreter executes the
+same kernel bodies, so CPU tests validate the exact TPU program).
 """
 from __future__ import annotations
-
-import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import gf, quant_pallas, ref, rs_pallas
-
-
-def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() == "cpu"
 
 
 def _pad_axis(x, axis: int, mult: int):
@@ -39,7 +28,7 @@ def _pad_axis(x, axis: int, mult: int):
 def rs_encode(data, r: int):
     """Systematic RS parity over packet rows: (k, B) uint8 -> (r, B) uint8."""
     x, b0 = _pad_axis(data, 1, rs_pallas.TILE_B)
-    out = rs_pallas.rs_encode(x, r, interpret=_interpret())
+    out = rs_pallas.rs_encode(x, r)
     return out[:, :b0]
 
 
@@ -50,8 +39,7 @@ def rs_decode(survivors, k: int, r: int, missing, parity_avail):
     if not missing:
         return survivors[:0]
     x, b0 = _pad_axis(survivors, 1, rs_pallas.TILE_B)
-    out = rs_pallas.rs_decode(x, k, r, missing, parity_avail,
-                              interpret=_interpret())
+    out = rs_pallas.rs_decode(x, k, r, missing, parity_avail)
     return out[:, :b0]
 
 
@@ -75,13 +63,12 @@ def quant_int8(x):
     """Flat float array -> (q int8, scales f32, original length)."""
     flat = x.reshape(-1)
     padded, n0 = _pad_axis(flat, 0, _QCHUNK)
-    q, s = quant_pallas.quant_int8(padded, QUANT_BLOCK, interpret=_interpret())
+    q, s = quant_pallas.quant_int8(padded, QUANT_BLOCK)
     return q, s, n0
 
 
 def dequant_int8(q, scales, n0: int, dtype=jnp.float32):
-    out = quant_pallas.dequant_int8(q, scales, QUANT_BLOCK, dtype,
-                                    interpret=_interpret())
+    out = quant_pallas.dequant_int8(q, scales, QUANT_BLOCK, dtype)
     return out[:n0]
 
 

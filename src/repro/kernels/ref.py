@@ -94,9 +94,10 @@ def fleet_offered_load_ref(routes, rates, split, n_links: int):
 
 def fleet_offered_load_tiles_ref(routes, rates, split, n_links: int,
                                  n_boundary: int):
-    """Oracle for the per-shard tiled scatter (fleet_pallas
-    .link_scatter_tiles): the (n_links + 1,) reference buffer split at
-    `n_links - n_boundary` into (private, boundary + scratch) tiles.
+    """Oracle for a per-shard scatter under a halo exchange
+    (links.offered_load(halo=n_boundary)): the (n_links + 1,) reference
+    buffer split at `n_links - n_boundary` into (private, boundary +
+    scratch) tiles — only the boundary tile is exchanged.
     Only the real links are part of the contract — the scratch slot is
     backend-specific (see fleet_offered_load_ref).
     """

@@ -21,12 +21,13 @@ comfortably inside the ~16 MiB v5e VMEM even with double buffering.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import gf
+from repro.kernels import gf, interpret_mode
 
 TILE_B = 2048
 
@@ -53,11 +54,12 @@ def _gf_matmul_kernel(x_ref, o_ref, *, coeffs):
 
 
 @functools.partial(jax.jit, static_argnames=("coeffs", "interpret"))
-def gf_matmul(x, coeffs: tuple[tuple[int, ...], ...], interpret: bool = True):
+def gf_matmul(x, coeffs: tuple[tuple[int, ...], ...],
+              interpret: Optional[bool] = None):
     """(M,K) static GF coeffs x (K,B) uint8 -> (M,B) uint8 via pallas_call.
 
-    B must be a multiple of TILE_B (ops.py pads).  interpret=True executes
-    the kernel body in Python on CPU (this container); on TPU pass False.
+    B must be a multiple of TILE_B (ops.py pads).  `interpret` resolves
+    through repro.kernels.interpret_mode (compiled on a TPU).
     """
     K, B = x.shape
     M = len(coeffs)
@@ -69,18 +71,19 @@ def gf_matmul(x, coeffs: tuple[tuple[int, ...], ...], interpret: bool = True):
         in_specs=[pl.BlockSpec((K, TILE_B), lambda i: (0, i))],
         out_specs=pl.BlockSpec((M, TILE_B), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((M, B), jnp.uint8),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x)
 
 
-def rs_encode(data, r: int, interpret: bool = True):
+def rs_encode(data, r: int, interpret: Optional[bool] = None):
     """Systematic RS parity: data (k, B) uint8 -> (r, B) uint8."""
     k = data.shape[0]
     return gf_matmul(data, gf.rs_generator_rows(k, r), interpret=interpret)
 
 
 def rs_decode(survivors, k: int, r: int, missing: tuple[int, ...],
-              parity_avail: tuple[int, ...], interpret: bool = True):
+              parity_avail: tuple[int, ...],
+              interpret: Optional[bool] = None):
     """Reconstruct `missing` data rows from survivor rows.
 
     survivors: (n_sur, B) uint8 ordered [present data asc] + [avail parity

@@ -6,22 +6,17 @@ import math
 
 import jax
 
+from repro.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(16,16) data x model single pod; (2,16,16) pod x data x model multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = math.prod(shape)
-    devices = jax.devices()
-    if len(devices) < n:
+    if len(jax.devices()) < n:
         raise RuntimeError(
-            f"mesh {shape} needs {n} devices, have {len(devices)} — run under "
-            "XLA_FLAGS=--xla_force_host_platform_device_count=512 (dryrun.py "
-            "sets this automatically)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh over the first prod(shape) devices (tests, examples)."""
-    n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+            f"mesh {shape} needs {n} devices, have {len(jax.devices())} — "
+            "run under XLA_FLAGS=--xla_force_host_platform_device_count=512 "
+            "(dryrun.py sets this automatically)")
+    return make_mesh(shape, axes)

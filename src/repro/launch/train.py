@@ -56,7 +56,7 @@ def main() -> None:
         dims = tuple(int(x) for x in args.mesh.split("x"))
         names = ("pod", "data", "model")[-len(dims):] if len(dims) < 3 else \
             ("pod", "data", "model")
-        mesh = jax.make_mesh(dims, names)
+        mesh = sharding.make_mesh(dims, names)
 
     run = RunConfig(learning_rate=args.lr, uno_enabled=args.uno,
                     uno_chunks=args.uno_chunks, seed=args.seed)

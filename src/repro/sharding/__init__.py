@@ -9,11 +9,13 @@ device, a (data, model) pod, or a (pod, data, model) multi-pod mesh.
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis -> tuple of mesh axes (joined) in priority order.  A mesh axis is
 # used only if present in the active mesh.
@@ -31,33 +33,26 @@ DEFAULT_RULES: dict[str, tuple[str, ...]] = {
 }
 
 
-def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = True):
-    """`jax.shard_map` with the post-0.6 signature on any installed jax.
+def make_mesh(shape: Sequence[int], axes: Sequence[str],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """Mesh of `shape` over `devices` (default: the first prod(shape) local
+    devices) in their given order, with every axis `AxisType.Auto`.
 
-    Newer releases expose `jax.shard_map(..., axis_names=..., check_vma=...)`
-    directly; on older ones this translates to the experimental API, where
-    `auto` is the complement of `axis_names` over the mesh and `check_rep`
-    plays the role of `check_vma`.
+    `jax.make_mesh` defaults its axes to Explicit, under which plain
+    gathers and reshapes of sharded operands raise `ShardingTypeError`
+    (the code here relies on GSPMD propagation and manual `shard_map`
+    regions, which Auto axes give), and it refuses a subset of a TPU
+    host's chips — such as three DCs on a four-chip host.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _esm
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma, auto=auto)
-
-
-def set_mesh(mesh):
-    """Context manager activating `mesh`: `jax.set_mesh` where available,
-    else the Mesh object's own context manager (pre-0.6 equivalent)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    n = math.prod(shape)
+    devices = jax.devices()[:n] if devices is None else list(devices)
+    if len(devices) != n:
+        raise ValueError(f"mesh {tuple(shape)} needs {n} devices, "
+                         f"got {len(devices)}")
+    grid = np.empty(n, dtype=object)
+    grid[:] = devices
+    return Mesh(grid.reshape(tuple(shape)), tuple(axes),
+                axis_types=(AxisType.Auto,) * len(axes))
 
 
 class _State(threading.local):

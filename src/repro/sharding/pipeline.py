@@ -31,8 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding import shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class PipelineConfig:
@@ -99,9 +97,9 @@ def pipeline_apply(cfg: PipelineConfig, mesh: Mesh, stage_fn: Callable,
         return outs
 
     spec_params = jax.tree.map(lambda _: P(ax), stage_params)
-    return shard_map(per_stage, mesh=mesh,
-                     in_specs=(spec_params, P()), out_specs=P(),
-                     axis_names={ax}, check_vma=False)(
+    return jax.shard_map(per_stage, mesh=mesh,
+                         in_specs=(spec_params, P()), out_specs=P(),
+                         axis_names={ax}, check_vma=False)(
         stage_params, x_micro)
 
 

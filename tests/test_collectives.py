@@ -29,7 +29,7 @@ from repro import sharding, train
 from repro.configs.base import reduced, RunConfig
 from repro.configs.registry import get_config
 from repro.core.uno_collectives import make_uno_grad_sync
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = sharding.make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = reduced(get_config("granite-8b"))
 run = RunConfig(uno_chunks=4)
 with sharding.use_mesh(mesh):
@@ -63,15 +63,15 @@ import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import PartitionSpec as P
 from repro.configs.base import RunConfig
 from repro.core.uno_collectives import _pod_ring_psum
-from repro.sharding import set_mesh, shard_map
-mesh = jax.make_mesh((4, 2), ("pod", "data"))
+from repro.sharding import make_mesh
+mesh = make_mesh((4, 2), ("pod", "data"))
 run = RunConfig(uno_chunks=2)
 n = 4 * 8 * 256 * 2
 x = jnp.asarray(np.random.default_rng(0).normal(size=(4, n)).astype(np.float32))
-f = shard_map(lambda v: _pod_ring_psum(v[0], run, 4),
-              mesh=mesh, in_specs=P("pod"), out_specs=P(),
-              axis_names={"pod", "data"}, check_vma=False)
-with set_mesh(mesh):
+f = jax.shard_map(lambda v: _pod_ring_psum(v[0], run, 4),
+                  mesh=mesh, in_specs=P("pod"), out_specs=P(),
+                  axis_names={"pod", "data"}, check_vma=False)
+with jax.set_mesh(mesh):
     out = jax.jit(f)(x)
 want = np.asarray(x).mean(axis=0)
 err = float(np.max(np.abs(np.asarray(out) - want)))

@@ -125,7 +125,14 @@ def test_layout_csr_invariants():
             assert np.all(link[ptr[l]:ptr[l + 1]] == l)
         n_real = int(np.sum(np.asarray(net.routes) >= 0))
         assert int(ptr[net.n_links]) == n_real
-        assert np.asarray(lay.pad_idx).shape == np.asarray(net.routes).shape
+        routes = np.asarray(net.routes)
+        hop = np.asarray(lay.hop_idx)
+        n = routes.shape[0]
+        assert hop.shape[:2] == routes.shape[:0:-1] and hop.shape[2] >= n
+        np.testing.assert_array_equal(
+            hop[..., :n],
+            np.where(routes >= 0, routes, net.n_links).transpose(2, 1, 0))
+        assert np.all(hop[..., n:] == net.n_links)
 
 
 def test_pallas_link_gathers_match_reference():
@@ -136,9 +143,9 @@ def test_pallas_link_gathers_match_reference():
         scale = jnp.asarray(rng.uniform(0.05, 1.0, net.n_links), jnp.float32)
         clean = jnp.asarray(rng.uniform(0.0, 1.0, net.n_links), jnp.float32)
         delay = jnp.asarray(rng.uniform(0.0, 50.0, net.n_links), jnp.float32)
-        pad_idx = jnp.where(net.routes >= 0, net.routes, net.n_links)
-        got = fleet_pallas.link_gathers(pad_idx, scale, clean, delay,
-                                        block=4)
+        hop_idx = L.hop_major_idx(net.routes, net.n_links)
+        got = fleet_pallas.link_gathers(hop_idx, scale, clean, delay,
+                                        net.routes.shape[0], block=4)
         ref = kref.fleet_link_gathers_ref(net.routes, scale, clean, delay)
         for g, r in zip(got, ref):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
@@ -149,8 +156,9 @@ def test_pallas_scatter_pads_nondivisible_flow_counts():
     rng = np.random.default_rng(9)
     net = _random_net(rng, n_links=5, n_flows=7, n_paths=2, max_hops=3)
     rates, split = _random_rates_split(rng, net)
-    pad_idx = jnp.where(net.routes >= 0, net.routes, net.n_links)
-    got = fleet_pallas.link_scatter(pad_idx, rates[:, None] * split,
+    # an index without lane padding: the wrapper pads it to the block
+    hop_idx = L.hop_major_idx(net.routes, net.n_links)[..., :7]
+    got = fleet_pallas.link_scatter(hop_idx, rates[:, None] * split,
                                     net.n_links, block=4)
     ref = kref.fleet_offered_load_ref(net.routes, rates, split, net.n_links)
     # real links must match exactly; the scratch slot is allowed to differ
@@ -308,16 +316,21 @@ def test_stack_scenarios_strips_mismatched_tables():
 
 
 def test_pick_block():
-    """Block size tracks the flow count instead of the old hardcoded 512:
-    tiny fleets keep the f32 sublane minimum, mid sizes scale in powers
-    of two, large fleets saturate at BLOCK_FLOWS."""
-    assert fleet_pallas.pick_block(1) == 8
-    assert fleet_pallas.pick_block(1000) == 128
-    assert fleet_pallas.pick_block(4096) == 512
-    assert fleet_pallas.pick_block(1_000_000) == fleet_pallas.BLOCK_FLOWS
+    """Block size tracks the route size: small inputs keep one 128-lane
+    tile, mid sizes scale in powers of two, large ones saturate at
+    BLOCK_ROWS, and a gather's whole-link-axis one-hot tile stays inside
+    the VMEM budget."""
+    fp = fleet_pallas
+    assert fp.pick_block(1) == fp.LANES
+    assert fp.pick_block(2048) == 256
+    assert fp.pick_block(4096) == 512
+    assert fp.pick_block(1_000_000) == fp.BLOCK_ROWS
     for n in (1, 3, 77, 1000, 5000, 10 ** 6):
-        b = fleet_pallas.pick_block(n)
-        assert b & (b - 1) == 0 and 8 <= b <= fleet_pallas.BLOCK_FLOWS
+        b = fp.pick_block(n)
+        assert b & (b - 1) == 0 and fp.LANES <= b <= fp.BLOCK_ROWS
+    # fat-tree k=8 gather: 1,617 link columns cap the block at 128 lanes
+    b = fp.pick_block(10 ** 6, 1617)
+    assert b * 1664 <= fp.ONEHOT_BUDGET and b >= fp.LANES
 
 
 # --------------------------------------------------- locality shard plans
@@ -388,34 +401,35 @@ def test_plan_shards_dumbbell_boundary_is_tiny():
 
 
 def test_scatter_tiles_matches_reference():
-    """The private/boundary-tiled Pallas scatter == the reference buffer
-    split at the boundary, over random routes incl. -1 padding."""
+    """The Pallas scatter buffer split at the boundary into the private
+    tile and the boundary tile a halo exchange reduces == the reference
+    tiles, over random routes incl. -1 padding and repeated hops."""
     rng = np.random.default_rng(21)
     for _ in range(6):
         net = _random_net(rng)
         rates, split = _random_rates_split(rng, net)
         n_boundary = int(rng.integers(1, net.n_links))
-        pad_idx = jnp.where(net.routes >= 0, net.routes, net.n_links)
-        priv, bnd = fleet_pallas.link_scatter_tiles(
-            pad_idx, rates[:, None] * split, net.n_links, n_boundary,
-            block=4)
+        n_priv = net.n_links - n_boundary
+        buf = fleet_pallas.link_scatter(
+            L.hop_major_idx(net.routes, net.n_links),
+            rates[:, None] * split, net.n_links, block=4)
+        priv, bnd = buf[:n_priv], buf[n_priv:]
         rp, rb = kref.fleet_offered_load_tiles_ref(
             net.routes, rates, split, net.n_links, n_boundary)
-        assert priv.shape == (net.n_links - n_boundary,)
-        assert bnd.shape == (n_boundary + 1,)
+        assert priv.shape == rp.shape == (n_priv,)
+        assert bnd.shape == rb.shape == (n_boundary + 1,)
         got = np.concatenate([np.asarray(priv), np.asarray(bnd)])
         want = np.concatenate([np.asarray(rp), np.asarray(rb)])
-        # real links must match; the scratch slot is backend-specific
-        np.testing.assert_allclose(got[:net.n_links], want[:net.n_links],
-                                   atol=1e-6)
-    with pytest.raises(ValueError):
-        fleet_pallas.link_scatter_tiles(pad_idx, rates[:, None] * split,
-                                        net.n_links, 0)
+        # real links must match (normalized: the kernel sums in a
+        # different order); the scratch slot is backend-specific
+        scale = max(1.0, float(np.abs(want[:net.n_links]).max()))
+        np.testing.assert_allclose(got[:net.n_links] / scale,
+                                   want[:net.n_links] / scale, atol=1e-6)
 
 
 def test_offered_load_pallas_halo_tiles():
-    """offered_load(backend="pallas", halo=...) routes through the tiled
-    kernel and still reproduces the reference loads."""
+    """offered_load(backend="pallas", halo=...) still reproduces the
+    reference loads."""
     rng = np.random.default_rng(23)
     for _ in range(4):
         net = L.with_layout(_random_net(rng))
@@ -425,7 +439,8 @@ def test_offered_load_pallas_halo_tiles():
         halo = int(rng.integers(1, net.n_links))
         got = np.asarray(L.offered_load(net, rates, split,
                                         backend="pallas", halo=halo))
-        np.testing.assert_allclose(got, ref, atol=1e-6)
+        scale = max(1.0, float(np.abs(ref).max()))
+        np.testing.assert_allclose(got / scale, ref / scale, atol=1e-6)
 
 
 def test_sharded_one_device_mesh_matches_single():

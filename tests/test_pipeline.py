@@ -29,11 +29,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np, json
 from jax.sharding import PartitionSpec as P
-from repro.sharding import set_mesh
+from repro.sharding import make_mesh
 from repro.sharding.pipeline import PipelineConfig, pipeline_apply, split_stack
 
 L, D, MB, M, S = 8, 16, 4, 8, 4
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = make_mesh((4,), ("pipe",))
 rng = np.random.default_rng(0)
 W = jnp.asarray(rng.normal(size=(L, D, D)).astype(np.float32) * 0.2)
 x = jnp.asarray(rng.normal(size=(M, MB, D)).astype(np.float32))
@@ -65,7 +65,7 @@ def loss_pipe(Wst, x):
 def loss_seq(W, x):
     return jnp.sum(sequential(W, x) ** 2)
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     piped = jax.jit(lambda Wst, x: pipeline_apply(cfg, mesh, stage_fn, Wst, x))
     y_pipe = piped(Wst, x)
     g_pipe = jax.jit(jax.grad(loss_pipe))(Wst, x)
