@@ -45,8 +45,7 @@ Acceptance targets:
     unique-path-segment table on deep-multipath routes) and its entry
     splits timing into spec_build_s / compile_s / warm_s and records
     n_unique_paths next to n_flows so the dedupe ratio is visible in the
-    trajectory.  `--profile` wraps that point in jax.profiler and prints
-    the per-phase timings; `--check-equivalence` pins the pt backends to
+    trajectory.  `--check-equivalence` pins the pt backends to
     the reference scatter on the smoke fat tree (CI runs it under a
     2-forced-device mesh so the sharded/halo variant is covered too);
     `--block` overrides the Pallas flow-block size (default: picked from
@@ -70,7 +69,6 @@ for the figure registry (benchmarks.run).
 from __future__ import annotations
 
 import argparse
-import contextlib
 import datetime
 import json
 import os
@@ -297,8 +295,7 @@ def _point(n_flows, n_epochs, *, variant, path, warm_s, cold_s=None,
 
 
 def _fat_tree_layout_point(ft_k: int, ft_n: int, ft_ne: int, *,
-                           backend: str = "auto", block=None,
-                           profile_dir=None) -> dict:
+                           backend: str = "auto", block=None) -> dict:
     """Time the fat-tree layout point with its phases split out.
 
     spec_build_s: scenario compile + trimmed-layout rebuild, including
@@ -307,18 +304,14 @@ def _fat_tree_layout_point(ft_k: int, ft_n: int, ft_ne: int, *,
     to hide inside cold_s); warm_s: the best warm scan.  The entry also
     records n_unique_paths — the table's unique-segment count (null when
     the scenario compiled flat) — next to n_flow_paths, so the dedupe
-    ratio is visible in the trajectory.  `profile_dir` wraps the timed
-    runs in jax.profiler.trace for TensorBoard-readable per-op detail.
+    ratio is visible in the trajectory.
     """
     t0 = time.time()
     net, params, ii, lb, _ = _scenario(ft_n, True, "fat_tree", ft_k)
     fast_net = fl.with_layout(net, trim=True)
     spec_build = time.time() - t0
-    ctx = (jax.profiler.trace(profile_dir) if profile_dir
-           else contextlib.nullcontext())
-    with ctx:
-        cold, warm = _time_simulate(fast_net, params, ft_ne, is_inter=ii,
-                                    lb=lb, backend=backend, block=block)
+    cold, warm = _time_simulate(fast_net, params, ft_ne, is_inter=ii,
+                                lb=lb, backend=backend, block=block)
     pt = fast_net.layout.path_table
     return _point(
         ft_n, ft_ne, variant=f"fat_tree_k{ft_k}", path="layout",
@@ -763,7 +756,7 @@ def _sharded_points(n: int, ne: int, points: list,
 
 
 def scaling_curve(mode: str = "full", *, backend: str = "auto",
-                  block=None, profile_dir=None) -> dict:
+                  block=None) -> dict:
     """Grow the n_flows scaling curve and append it to the
     BENCH_fleetsim.json trajectory.
 
@@ -772,8 +765,7 @@ def scaling_curve(mode: str = "full", *, backend: str = "auto",
     backend/block override the load backend and Pallas flow-block size on
     the single-device layout points (default: "auto" picks the PathTable
     backend where a table is attached, and the block is sized from
-    n_flows); profile_dir wraps the fat-tree layout point in
-    jax.profiler.trace.
+    n_flows).
     """
     sizes = {"smoke": [10_000], "quick": [1_000, 10_000, 100_000],
              "full": [1_000, 10_000, 100_000, 1_000_000]}[mode]
@@ -814,8 +806,7 @@ def scaling_curve(mode: str = "full", *, backend: str = "auto",
     ft_ne = 300 if mode == "smoke" else 200
     variant = f"fat_tree_k{ft_k}"
     points.append(_fat_tree_layout_point(ft_k, ft_n, ft_ne, backend=backend,
-                                         block=block,
-                                         profile_dir=profile_dir))
+                                         block=block))
     ft_paths = ((("sharded2-local", True),) if mode == "smoke" else
                 (("sharded2-local", True), ("sharded2", False)))
     _sharded_points(ft_n, ft_ne, points, speedups, kind="fat_tree",
@@ -997,13 +988,6 @@ def _main() -> None:
                          "fat tree, fast-path guards)")
     ap.add_argument("--quick", action="store_true",
                     help="with --scaling: stop at 100k flows")
-    ap.add_argument("--profile", action="store_true",
-                    help="profile the fat-tree layout point with "
-                         "jax.profiler.trace and print its phase split "
-                         "(spec_build_s / compile_s / warm_s)")
-    ap.add_argument("--profile-dir", default="results/profile",
-                    help="jax.profiler trace output dir for --profile "
-                         "(TensorBoard-readable; default %(default)s)")
     ap.add_argument("--backend", default="auto",
                     choices=list(fl.LOAD_BACKENDS),
                     help="load backend for the layout points (default "
@@ -1027,19 +1011,6 @@ def _main() -> None:
         _fault_smoke()
     elif args.check_equivalence:
         check_equivalence()
-    elif args.profile:
-        pathlib.Path(args.profile_dir).mkdir(parents=True, exist_ok=True)
-        ft_k, ft_n, ft_ne = (4, 12_000, 300) if args.smoke else \
-            (8, 100_000, 200)
-        rec = _fat_tree_layout_point(ft_k, ft_n, ft_ne,
-                                     backend=args.backend,
-                                     block=args.block,
-                                     profile_dir=args.profile_dir)
-        print(json.dumps({k: rec[k] for k in
-                          ("spec_build_s", "compile_s", "warm_s",
-                           "flow_epochs_per_s", "n_unique_paths")},
-                         indent=1))
-        print(f"profiler trace in {args.profile_dir}")
     elif args.scaling or args.smoke:
         mode = "smoke" if args.smoke else \
             ("quick" if args.quick else "full")

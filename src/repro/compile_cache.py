@@ -6,6 +6,12 @@ reuse it.  A later run finds an entry only where an earlier one wrote it, so
 the directory never depends on the process, the time or a temporary path:
 `JAX_COMPILATION_CACHE_DIR` when the environment sets it, else
 `<checkout>/.jax_cache` (listed in .gitignore).
+
+An entry's key covers the program's metadata too (each op's `op_name`,
+with the `fleetsim.<stage>` scopes, and its source line): JAX leaves it out
+by default, and an executable read back from the cache then names its ops
+as the source that first compiled it did, so a profile of this source
+would show another revision's stages.
 """
 from __future__ import annotations
 
@@ -21,8 +27,9 @@ def use_compile_cache() -> str:
     """Turn the persistent compilation cache on; returns its directory.
 
     Call before the first compile.  Where `JAX_COMPILATION_CACHE_DIR` is
-    set, JAX reads it itself and this changes nothing.
+    set, JAX reads it itself and this changes only the key.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -188,6 +188,7 @@ def make_step(net: L.FluidNet, params: FleetParams, scheme: str = "uno",
         fresh = init_state(params, net.n_links, n_paths=net.n_paths,
                            split0=L.uniform_split(net), rel=rel)
 
+    @jax.named_scope("fleetsim.cc")
     def step(state: FleetState, _):
         p = params
         act = state.active
@@ -486,12 +487,13 @@ def simulate(net: L.FluidNet, params: FleetParams, *, n_epochs: int,
     `fault` a compiled fault schedule (faults.make_schedule or the
     scenario compiler).
     """
-    if state0 is None:
-        state0 = _default_state(net, params, seed, rel, fault)
-    if is_inter is None:
-        is_inter = jnp.zeros_like(params.bdp, bool)
-    return _simulate(net, params, state0, is_inter, lb, churn, scheme,
-                     n_epochs, record, backend, block, rel, fault)
+    with jax.profiler.TraceAnnotation("fleetsim.dispatch"):
+        if state0 is None:
+            state0 = _default_state(net, params, seed, rel, fault)
+        if is_inter is None:
+            is_inter = jnp.zeros_like(params.bdp, bool)
+        return _simulate(net, params, state0, is_inter, lb, churn, scheme,
+                         n_epochs, record, backend, block, rel, fault)
 
 
 @functools.partial(jax.jit,

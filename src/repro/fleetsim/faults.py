@@ -144,6 +144,7 @@ def init_fault_carry(fault: FaultSchedule, seed: int = 0) -> FaultCarry:
         key=jax.random.fold_in(jax.random.PRNGKey(seed), 0xFA))
 
 
+@jax.named_scope("fleetsim.faults")
 def fault_modulation(fault: FaultSchedule, carry: FaultCarry, n_links: int):
     """One epoch of fault evaluation.
 
@@ -182,6 +183,7 @@ def fault_modulation(fault: FaultSchedule, carry: FaultCarry, n_links: int):
                                           key=key)
 
 
+@jax.named_scope("fleetsim.faults")
 def apply_modulation(net: L.FluidNet, cap_scale, p_extra) -> L.FluidNet:
     """This epoch's effective FluidNet: capacity (and the proportional
     phantom drain) scaled, extra loss composed into `p_loss` as an
@@ -197,6 +199,7 @@ def apply_modulation(net: L.FluidNet, cap_scale, p_extra) -> L.FluidNet:
     return net
 
 
+@jax.named_scope("fleetsim.faults")
 def degrade_split(net: L.FluidNet, split: jnp.ndarray, cap_scale,
                   pmask: jnp.ndarray) -> jnp.ndarray:
     """The epoch's effective send split with dead paths drained.

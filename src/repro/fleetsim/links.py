@@ -697,6 +697,7 @@ def halo_exchange(buf: jnp.ndarray, n_links: int, axis_name: str,
     return jnp.concatenate([buf[:lo], shared, buf[n_links:]])
 
 
+@jax.named_scope("fleetsim.offered_load")
 def offered_load(net: FluidNet, rates: jnp.ndarray,
                  split: Optional[jnp.ndarray] = None, *,
                  axis_name: Optional[str] = None,
@@ -925,39 +926,42 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
     load = offered_load(net, rates, split, axis_name=axis_name,
                         backend=rb, halo=halo, block=block,
                         nbr=nbr, n_shards=n_shards)
-    q_phys, q_phantom = step_queues(net, q_phys, q_phantom, load)
-    p_link = mark_prob(net, q_phys, q_phantom)
-    compressed = rb in ("pt", "pt_pallas")
-    if rb == "pallas":
-        from repro.kernels import fleet_pallas
-        sub_scale, sub_frac, sub_delay = fleet_pallas.link_gathers(
-            _hop_idx(net),
-            jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
-            1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS),
-            net.routes.shape[0], block=block)
-    elif rb == "pt_pallas":
-        from repro.kernels import fleet_pallas
-        pt = net.layout.path_table
-        sub_scale, sub_frac, sub_delay = fleet_pallas.path_table_gathers(
-            pt.pre_id, pt.suf_id, pt.seg_idx,
-            jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
-            1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS), block=block)
-    elif rb == "pt":
-        sub_scale, sub_frac, sub_delay = _pt_gathers(net, load, p_link,
-                                                     q_phys)
-    else:
-        sub_scale = subflow_scale(net, load)
-        sub_frac = subflow_mark_frac(net, p_link)
-        sub_delay = subflow_delay(net, q_phys)
-    loss_frac = _pt_loss_frac if compressed else subflow_loss_frac
-    if net.p_loss is not None:
-        sub_scale = sub_scale * (1.0 - loss_frac(net, net.p_loss))
-    p_drop = sub_loss = None
-    if with_loss:
-        p_drop = drop_prob(net, q_prev, load)
+    with jax.named_scope("fleetsim.link_gathers"):
+        q_phys, q_phantom = step_queues(net, q_phys, q_phantom, load)
+        p_link = mark_prob(net, q_phys, q_phantom)
+        compressed = rb in ("pt", "pt_pallas")
+        if rb == "pallas":
+            from repro.kernels import fleet_pallas
+            sub_scale, sub_frac, sub_delay = fleet_pallas.link_gathers(
+                _hop_idx(net),
+                jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
+                1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS),
+                net.routes.shape[0], block=block)
+        elif rb == "pt_pallas":
+            from repro.kernels import fleet_pallas
+            pt = net.layout.path_table
+            sub_scale, sub_frac, sub_delay = \
+                fleet_pallas.path_table_gathers(
+                    pt.pre_id, pt.suf_id, pt.seg_idx,
+                    jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
+                    1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS),
+                    block=block)
+        elif rb == "pt":
+            sub_scale, sub_frac, sub_delay = _pt_gathers(net, load, p_link,
+                                                         q_phys)
+        else:
+            sub_scale = subflow_scale(net, load)
+            sub_frac = subflow_mark_frac(net, p_link)
+            sub_delay = subflow_delay(net, q_phys)
+        loss_frac = _pt_loss_frac if compressed else subflow_loss_frac
         if net.p_loss is not None:
-            p_drop = 1.0 - (1.0 - p_drop) * (1.0 - net.p_loss)
-        sub_loss = loss_frac(net, p_drop)
+            sub_scale = sub_scale * (1.0 - loss_frac(net, net.p_loss))
+        p_drop = sub_loss = None
+        if with_loss:
+            p_drop = drop_prob(net, q_prev, load)
+            if net.p_loss is not None:
+                p_drop = 1.0 - (1.0 - p_drop) * (1.0 - net.p_loss)
+            sub_loss = loss_frac(net, p_drop)
     return LinkEpoch(load=load, q_phys=q_phys, q_phantom=q_phantom,
                      p_link=p_link, sub_scale=sub_scale, sub_frac=sub_frac,
                      sub_delay=sub_delay, p_drop=p_drop, sub_loss=sub_loss)

@@ -81,6 +81,7 @@ from __future__ import annotations
 import math
 from typing import NamedTuple, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 _EPS = 1e-9
@@ -301,6 +302,7 @@ def _effective_geometry(rel: RelParams, st: Optional[RelState]):
     return ec_k, ec_r, coef
 
 
+@jax.named_scope("fleetsim.reliability")
 def effective_eff(rel: RelParams, st: Optional[RelState]) -> jnp.ndarray:
     """Current goodput efficiency k/(k+r), ladder rung folded in."""
     if st is None or rel.ladder_eff is None:
@@ -337,6 +339,7 @@ def recovery_split(rel: RelParams, q: jnp.ndarray,
     return rec_window * scale, nack_window * scale
 
 
+@jax.named_scope("fleetsim.reliability")
 def rtx_rate(rel: RelParams, st: RelState, rate: jnp.ndarray,
              rtt: jnp.ndarray) -> jnp.ndarray:
     """Retransmit send rate (bytes/ns) drained from the NACK backlog.
@@ -348,6 +351,7 @@ def rtx_rate(rel: RelParams, st: RelState, rate: jnp.ndarray,
                        rel.rtx_cap * rate)
 
 
+@jax.named_scope("fleetsim.reliability")
 def rel_epoch(rel: RelParams, st: RelState, rate: jnp.ndarray,
               rtx: jnp.ndarray, wire: jnp.ndarray, loss_frac: jnp.ndarray,
               dt, rtt: jnp.ndarray):

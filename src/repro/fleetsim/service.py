@@ -438,31 +438,37 @@ class SweepService:
         replicas whose outputs are dropped).
         """
         queries = list(queries)
-        buckets: dict = {}
-        for i, q in enumerate(queries):
-            buckets.setdefault(_query_signature(q), []).append(i)
-        for sig, idxs in buckets.items():
-            q0 = queries[idxs[0]]
-            pos = 0
-            for live, rung in _cut_ladder(len(idxs), self.ladder):
-                take = idxs[pos:pos + live]
-                pos += live
-                cells = [queries[i].scenario for i in take]
-                seeds = [queries[i].seed for i in take]
-                if live < rung:
-                    cells += [cells[-1]] * (rung - live)
-                    seeds += [seeds[-1]] * (rung - live)
-                    self._stats["padded_cells"] += rung - live
-                final, rates = sweeps.run_grid(
-                    cells, scheme=q0.scheme, n_warm=q0.n_warm,
-                    n_meas=q0.n_meas, seeds=np.asarray(seeds, np.int32),
-                    backend=q0.backend)
+        with jax.profiler.TraceAnnotation("fleetsim.plan"):
+            buckets: dict = {}
+            for i, q in enumerate(queries):
+                buckets.setdefault(_query_signature(q), []).append(i)
+            batches = []
+            for idxs in buckets.values():
+                pos = 0
+                for live, rung in _cut_ladder(len(idxs), self.ladder):
+                    take = idxs[pos:pos + live]
+                    pos += live
+                    cells = [queries[i].scenario for i in take]
+                    seeds = [queries[i].seed for i in take]
+                    if live < rung:
+                        cells += [cells[-1]] * (rung - live)
+                        seeds += [seeds[-1]] * (rung - live)
+                        self._stats["padded_cells"] += rung - live
+                    batches.append((take, cells, seeds))
+        for take, cells, seeds in batches:
+            q0 = queries[take[0]]
+            final, rates = sweeps.run_grid(
+                cells, scheme=q0.scheme, n_warm=q0.n_warm,
+                n_meas=q0.n_meas, seeds=np.asarray(seeds, np.int32),
+                backend=q0.backend)
+            with jax.profiler.TraceAnnotation("fleetsim.wait"):
                 jax.block_until_ready(rates)
-                self._stats["batches"] += 1
-                self._stats["queries"] += live
-                for j, qid in enumerate(take):
-                    yield (qid, jax.tree.map(lambda a, k=j: a[k], final),
-                           rates[j])
+            self._stats["batches"] += 1
+            self._stats["queries"] += len(take)
+            with jax.profiler.TraceAnnotation("fleetsim.unstack"):
+                done = [(qid, jax.tree.map(lambda a, k=j: a[k], final),
+                         rates[j]) for j, qid in enumerate(take)]
+            yield from done
 
     def submit(self, queries: Sequence[SweepQuery]):
         """Blocking `stream`: list of (final_state, rates) in input order."""

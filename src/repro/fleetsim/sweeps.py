@@ -249,11 +249,14 @@ def run_grid(scenarios: Sequence[tuple], *, scheme: str = "uno",
                                 mesh, link_tier, unroll, backend)
         if out is not None:
             return out
-    nets, params, inters, lb, churn, rel, fault = stack_scenarios(scenarios)
-    sd = _grid_seeds(len(scenarios), seed, seeds)
-    return _grid_core(nets, params, inters, lb, churn, rel, sd, fault,
-                      scheme=scheme, n_warm=n_warm, n_meas=n_meas,
-                      backend=backend)
+    with jax.profiler.TraceAnnotation("fleetsim.stack"):
+        nets, params, inters, lb, churn, rel, fault = \
+            stack_scenarios(scenarios)
+        sd = _grid_seeds(len(scenarios), seed, seeds)
+    with jax.profiler.TraceAnnotation("fleetsim.dispatch"):
+        return _grid_core(nets, params, inters, lb, churn, rel, sd, fault,
+                          scheme=scheme, n_warm=n_warm, n_meas=n_meas,
+                          backend=backend)
 
 
 def run_grid_streamed(scenarios: Sequence[tuple], *, chunk: int = 8,
