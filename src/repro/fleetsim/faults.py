@@ -213,6 +213,8 @@ def degrade_split(net: L.FluidNet, split: jnp.ndarray, cap_scale,
     link resumes with the pre-fault weights instantly.
     """
     cs = jnp.concatenate([cap_scale, jnp.ones(1, cap_scale.dtype)])
+    # a gather of its own: the split it yields feeds the offered load, so
+    # it cannot join `link_epoch`'s one take, which runs after the load
     alive = L.hop_reduce(net, cs, jnp.min) > 0.0
     ok = pmask & alive
     any_alive = jnp.any(ok, axis=1)

@@ -90,8 +90,13 @@ ECN is the *expectation* of the engine's RED: linear ramp between the
 lo/hi thresholds of the marking queue (phantom where attached, else
 physical).  A subflow's mark fraction composes independently across hops:
 frac = 1 - prod(1 - p_link).  `link_epoch` runs the whole chain — offered
-load, queue step, mark probabilities, and the three link->flow gathers —
-against one layout in one call.
+load, queue step, mark probabilities, and the link->flow hop reductions —
+against one layout in one call.  On the flat backends every hop reduction
+after the load (bottleneck scale, marks, delay, and the loss survivals
+where the trace carries them) reads one take of a stacked (K, n_links + 1)
+per-link table through `hop_idx`: one gather an epoch, not K.  The
+dead-path drain (`faults.degrade_split`) keeps its own gather, since the
+split it yields feeds the offered load that pass follows.
 """
 from __future__ import annotations
 
@@ -251,9 +256,34 @@ def hop_reduce(net: FluidNet, vals: jnp.ndarray, reduce) -> jnp.ndarray:
     Gathers through the hop-major index, flows on the minor axis: the
     TPU compiler builds that gather in about a second at any fleet size,
     while the same gather through a flow-major (n, p, h) index takes it
-    minutes at 100k+ flows."""
+    minutes at 100k+ flows.  One gather per call: `link_epoch` reduces
+    all of an epoch's rows through `_hop_reduce_many` instead, one take
+    for every row."""
     n = net.routes.shape[0]
     return reduce(vals[_hop_idx(net)[..., :n]], axis=0).T
+
+
+def _hop_reduce_many(net: FluidNet, rows, reduces) -> list:
+    """`hop_reduce` of every per-link row in one gather: [(n_flows,
+    n_paths) `reduces[k]` over each subflow's hops of `rows[k]`].
+
+    The rows (each (n_links + 1,), identity in the pad slot) stack into a
+    (K, n_links + 1) table and ONE take through the hop index gathers all
+    K columns: a TPU gather fetches a whole tile per index, so K values an
+    index cost about what one does, where K separate gathers pay K tile
+    fetches per (hop, path, flow) entry.  Each gathered row then passes a
+    barrier before its reduction: where one fused reduction read all K
+    rows of the take, the v5e epoch scan answered wrong (PERF.md §6).
+    The gathered values and each reduction's axis are those of
+    `hop_reduce`, so every column equals its own `hop_reduce` bit for
+    bit."""
+    if not rows:
+        return []
+    n = net.routes.shape[0]
+    with jax.named_scope("hop_gather"):
+        g = jnp.stack(rows)[:, _hop_idx(net)[..., :n]]     # (K, h, p, n)
+    g = jax.lax.optimization_barrier(tuple(g[k] for k in range(len(rows))))
+    return [reduce(x, axis=0).T for x, reduce in zip(g, reduces)]
 
 
 def _blocked_csr(sort_key: np.ndarray, sort_val: np.ndarray, n_keys: int,
@@ -755,7 +785,27 @@ def offered_load(net: FluidNet, rates: jnp.ndarray,
 
 
 # ------------------------------------------------------- link -> flow gathers
-# (one hop-major gather + hop-axis reduce each, through `hop_reduce`)
+# (one hop-major gather + hop-axis reduce each, through `hop_reduce`;
+# `link_epoch` gathers all of an epoch's rows at once, `_hop_reduce_many`)
+# Per-link rows, padded with the identity of their hop reduction: padding
+# hops read the last slot.
+
+def _scale_row(net: FluidNet, load: jnp.ndarray) -> jnp.ndarray:
+    """min(1, cap/load) per link; pad slot 1.0 (no constraint)."""
+    s = jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS))
+    return jnp.concatenate([s, jnp.ones(1, s.dtype)])
+
+
+def _survival_row(p: jnp.ndarray) -> jnp.ndarray:
+    """1 - p per link; pad slot 1.0 (the hop product's identity)."""
+    return jnp.concatenate([1.0 - p, jnp.ones(1, p.dtype)])
+
+
+def _delay_row(net: FluidNet, q_phys: jnp.ndarray) -> jnp.ndarray:
+    """q/cap per link; pad slot 0.0 (the hop sum's identity)."""
+    return jnp.concatenate([q_phys / jnp.maximum(net.cap, _EPS),
+                            jnp.zeros(1, q_phys.dtype)])
+
 
 def subflow_scale(net: FluidNet, load: jnp.ndarray) -> jnp.ndarray:
     """(n_flows, n_paths) goodput/offered ratio: min over hops of cap/load.
@@ -764,9 +814,7 @@ def subflow_scale(net: FluidNet, load: jnp.ndarray) -> jnp.ndarray:
     proportionally to their arrival rates.  Padding paths report 1.0
     (harmless: their split weight is 0).
     """
-    s = jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS))
-    s = jnp.concatenate([s, jnp.ones(1, s.dtype)])   # pad slot: no constraint
-    return hop_reduce(net, s, jnp.min)
+    return hop_reduce(net, _scale_row(net, load), jnp.min)
 
 
 def bottleneck_scale(net: FluidNet, load: jnp.ndarray,
@@ -807,8 +855,7 @@ def subflow_loss_frac(net: FluidNet, p_drop: jnp.ndarray) -> jnp.ndarray:
 
     Same hop composition as `subflow_mark_frac`, on the overflow drop
     probabilities instead of the RED marks."""
-    keep = jnp.concatenate([1.0 - p_drop, jnp.ones(1, p_drop.dtype)])
-    return 1.0 - hop_reduce(net, keep, jnp.prod)
+    return 1.0 - hop_reduce(net, _survival_row(p_drop), jnp.prod)
 
 
 def _pt_gathers(net: FluidNet, load, p_link, q_phys):
@@ -820,11 +867,9 @@ def _pt_gathers(net: FluidNet, load, p_link, q_phys):
     ~1e-6 float tolerance.  Pad hops read the appended identity slot
     (1.0 / 1.0 / 0.0 — valid because scale <= 1)."""
     pt = net.layout.path_table
-    s = jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS))
-    s = jnp.concatenate([s, jnp.ones(1, s.dtype)])
-    clean = jnp.concatenate([1.0 - p_link, jnp.ones(1, p_link.dtype)])
-    d = jnp.concatenate([q_phys / jnp.maximum(net.cap, _EPS),
-                         jnp.zeros(1, q_phys.dtype)])
+    s = _scale_row(net, load)
+    clean = _survival_row(p_link)
+    d = _delay_row(net, q_phys)
     seg_scale = jnp.min(s[pt.seg_idx], axis=1)       # (U,)
     seg_clean = jnp.prod(clean[pt.seg_idx], axis=1)
     seg_delay = jnp.sum(d[pt.seg_idx], axis=1)
@@ -838,8 +883,7 @@ def _pt_loss_frac(net: FluidNet, p_drop: jnp.ndarray) -> jnp.ndarray:
     """`subflow_loss_frac` through the PathTable: survival products per
     unique segment, composed per subflow across the prefix/suffix split."""
     pt = net.layout.path_table
-    keep = jnp.concatenate([1.0 - p_drop, jnp.ones(1, p_drop.dtype)])
-    seg_keep = jnp.prod(keep[pt.seg_idx], axis=1)
+    seg_keep = jnp.prod(_survival_row(p_drop)[pt.seg_idx], axis=1)
     return 1.0 - seg_keep[pt.pre_id] * seg_keep[pt.suf_id]
 
 
@@ -853,8 +897,7 @@ def mark_prob(net: FluidNet, q_phys: jnp.ndarray,
 
 def subflow_mark_frac(net: FluidNet, p_link: jnp.ndarray) -> jnp.ndarray:
     """(n_flows, n_paths) mark fraction: 1 - prod over hops of (1 - p)."""
-    clean = jnp.concatenate([1.0 - p_link, jnp.ones(1, p_link.dtype)])
-    return 1.0 - hop_reduce(net, clean, jnp.prod)
+    return 1.0 - hop_reduce(net, _survival_row(p_link), jnp.prod)
 
 
 def path_mark_frac(net: FluidNet, p_link: jnp.ndarray,
@@ -870,9 +913,7 @@ def subflow_delay(net: FluidNet, q_phys: jnp.ndarray) -> jnp.ndarray:
     The capacity floor keeps a faulted (cap == 0) link's delay finite —
     huge, which correctly saturates the delay-gated reactions, but never
     NaN/Inf in the carry (repro.fleetsim.faults)."""
-    d = jnp.concatenate([q_phys / jnp.maximum(net.cap, _EPS),
-                         jnp.zeros(1, q_phys.dtype)])
-    return hop_reduce(net, d, jnp.sum)
+    return hop_reduce(net, _delay_row(net, q_phys), jnp.sum)
 
 
 def path_delay(net: FluidNet, q_phys: jnp.ndarray,
@@ -892,11 +933,20 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
                nbr: Optional[jnp.ndarray] = None,
                n_shards: Optional[int] = None) -> LinkEpoch:
     """One epoch of link physics in one call: offered load -> queue step ->
-    mark probabilities -> the three link->flow gathers.
+    mark probabilities -> the link->flow hop reductions.
 
-    The gathers share one `hop_idx` read per call via the layout; with
-    `backend="pallas"` they run as one fused kernel pass over the route
-    tensor (repro.kernels.fleet_pallas.link_gathers), and with the
+    Every hop reduction here reads only values known once the load is:
+    cap/load (min), 1 - p_link (prod), q/cap (sum), and the survivals
+    1 - p of `p_loss` and of the composed `p_drop` (prod).  On the flat
+    backends ("reference", "segment", "csr") their per-link rows stack
+    into one (K, n_links + 1) table that ONE take through `hop_idx`
+    gathers (`_hop_reduce_many`, under the "hop_gather" scope), K = 3 to
+    5 as the trace carries the loss rows; the result equals the separate
+    `subflow_*` gathers bit for bit.  (`faults.degrade_split` gathers
+    apart: its split feeds the offered load this pass follows.)  With
+    `backend="pallas"` the three reductions run as one fused kernel pass
+    over the route tensor (repro.kernels.fleet_pallas.link_gathers) and
+    the loss rows share one take, and with the
     PathTable backends ("pt" / "pt_pallas", also what "auto" picks when
     the layout carries a table) each gather reduces once per UNIQUE path
     segment before two per-subflow takes compose the halves — including
@@ -908,11 +958,9 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
     `with_loss=True` (a trace-time flag — the default trace pays zero
     overhead) additionally computes the queue-overflow drop probabilities
     from the PRE-step queues and composes them per subflow
-    (`p_drop`/`sub_loss`) for the reliability axis.  The loss gather runs
-    as a plain jnp gather on every backend, including pallas (the fused
-    kernel carries exactly three gathers).  Under sharding this needs no
-    extra exchange: p_drop reads the carried queues and post-halo loads,
-    both already correct on every link a local flow touches.
+    (`p_drop`/`sub_loss`) for the reliability axis.  Under sharding this
+    needs no extra exchange: p_drop reads the carried queues and post-halo
+    loads, both already correct on every link a local flow touches.
 
     A net with `p_loss` (configured random loss) additionally thins
     `sub_scale` by each subflow's survival through its lossy hops —
@@ -929,7 +977,13 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
     with jax.named_scope("fleetsim.link_gathers"):
         q_phys, q_phantom = step_queues(net, q_phys, q_phantom, load)
         p_link = mark_prob(net, q_phys, q_phantom)
-        compressed = rb in ("pt", "pt_pallas")
+        p_drop = None
+        if with_loss:
+            p_drop = drop_prob(net, q_prev, load)
+            if net.p_loss is not None:
+                p_drop = 1.0 - (1.0 - p_drop) * (1.0 - net.p_loss)
+        # the loss rows: the random-loss thinning, then the composed loss
+        lossy = [p for p in (net.p_loss, p_drop) if p is not None]
         if rb == "pallas":
             from repro.kernels import fleet_pallas
             sub_scale, sub_frac, sub_delay = fleet_pallas.link_gathers(
@@ -937,6 +991,8 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
                 jnp.minimum(1.0, net.cap / jnp.maximum(load, _EPS)),
                 1.0 - p_link, q_phys / jnp.maximum(net.cap, _EPS),
                 net.routes.shape[0], block=block)
+            keeps = _hop_reduce_many(net, [_survival_row(p) for p in lossy],
+                                     [jnp.prod] * len(lossy))
         elif rb == "pt_pallas":
             from repro.kernels import fleet_pallas
             pt = net.layout.path_table
@@ -950,18 +1006,19 @@ def link_epoch(net: FluidNet, rates: jnp.ndarray, split: jnp.ndarray,
             sub_scale, sub_frac, sub_delay = _pt_gathers(net, load, p_link,
                                                          q_phys)
         else:
-            sub_scale = subflow_scale(net, load)
-            sub_frac = subflow_mark_frac(net, p_link)
-            sub_delay = subflow_delay(net, q_phys)
-        loss_frac = _pt_loss_frac if compressed else subflow_loss_frac
+            sub_scale, keep, sub_delay, *keeps = _hop_reduce_many(
+                net, [_scale_row(net, load), _survival_row(p_link),
+                      _delay_row(net, q_phys)]
+                + [_survival_row(p) for p in lossy],
+                [jnp.min, jnp.prod, jnp.sum] + [jnp.prod] * len(lossy))
+            sub_frac = 1.0 - keep
+        if rb in ("pt", "pt_pallas"):
+            loss_fracs = [_pt_loss_frac(net, p) for p in lossy]
+        else:
+            loss_fracs = [1.0 - k for k in keeps]
         if net.p_loss is not None:
-            sub_scale = sub_scale * (1.0 - loss_frac(net, net.p_loss))
-        p_drop = sub_loss = None
-        if with_loss:
-            p_drop = drop_prob(net, q_prev, load)
-            if net.p_loss is not None:
-                p_drop = 1.0 - (1.0 - p_drop) * (1.0 - net.p_loss)
-            sub_loss = loss_frac(net, p_drop)
+            sub_scale = sub_scale * (1.0 - loss_fracs[0])
+        sub_loss = loss_fracs[-1] if with_loss else None
     return LinkEpoch(load=load, q_phys=q_phys, q_phantom=q_phantom,
                      p_link=p_link, sub_scale=sub_scale, sub_frac=sub_frac,
                      sub_delay=sub_delay, p_drop=p_drop, sub_loss=sub_loss)

@@ -3,7 +3,9 @@ Pallas link aggregation vs the original scatter), the fused Pallas
 link->flow gathers, locality shard plans + halo-exchange sharded steady
 state vs single device, and the compensated fairness reductions at 10^5
 flows."""
+import functools
 import json
+import re
 import subprocess
 import sys
 
@@ -198,6 +200,146 @@ def test_layout_backends_require_layout():
     for backend in ("pt", "pt_pallas"):
         with pytest.raises(ValueError):
             L.offered_load(flat, jnp.ones(2), backend=backend)
+
+
+# ------------------------------------------------ one hop gather an epoch
+
+_LOSS_CASES = {"clean": (False, False), "p_loss": (True, False),
+               "with_loss": (False, True), "both": (True, True)}
+
+
+def _gather_net(topo: str, p_loss: bool, seed: int = 11) -> L.FluidNet:
+    """A multipath dumbbell or a k=4 fat tree, both with a flat layout,
+    carrying random per-link loss when `p_loss`."""
+    if topo == "dumbbell_mp":
+        net, _, _ = dumbbell(4, 6, multipath=True)
+    else:
+        from repro.scenarios import fat_tree_spec, to_fleetsim
+        net = to_fleetsim(fat_tree_spec(k=4, n_wan=4, n_flows=24, n_paths=4,
+                                        seed=2)).net
+    if p_loss:
+        rng = np.random.default_rng(seed)
+        net = net._replace(p_loss=jnp.asarray(
+            rng.uniform(0.0, 0.05, net.n_links), jnp.float32))
+    return net
+
+
+def _epoch_inputs(net: L.FluidNet, seed: int):
+    """Send rates that overload some links, a random split, and queues
+    that overflow some links (so `p_drop` is nonzero)."""
+    rng = np.random.default_rng(seed)
+    n, p = net.routes.shape[:2]
+    rates = jnp.asarray(rng.uniform(0.0, 4.0, n), jnp.float32) * \
+        jnp.max(net.cap)
+    split = L.normalize_split(
+        jnp.asarray(rng.uniform(0, 1, (n, p)), jnp.float32), L.path_mask(net))
+    qp = jnp.asarray(rng.uniform(0, 1, net.n_links), jnp.float32) * net.qcap
+    qv = jnp.asarray(rng.uniform(0, 1, net.n_links), jnp.float32) * net.vcap
+    return rates, split, qp, qv
+
+
+def _per_column(net, rates, split, qp, qv, *, with_loss):
+    """`link_epoch`'s flow-side outputs, one public gather per column."""
+    load = L.offered_load(net, rates, split, backend="csr")
+    q_phys, q_phantom = L.step_queues(net, qp, qv, load)
+    p_link = L.mark_prob(net, q_phys, q_phantom)
+    scale = L.subflow_scale(net, load)
+    if net.p_loss is not None:
+        scale = scale * (1.0 - L.subflow_loss_frac(net, net.p_loss))
+    sub_loss = None
+    if with_loss:
+        p_drop = L.drop_prob(net, qp, load)
+        if net.p_loss is not None:
+            p_drop = 1.0 - (1.0 - p_drop) * (1.0 - net.p_loss)
+        sub_loss = L.subflow_loss_frac(net, p_drop)
+    return (scale, L.subflow_mark_frac(net, p_link),
+            L.subflow_delay(net, q_phys), sub_loss)
+
+
+def _fused_columns(net, rates, split, qp, qv, *, with_loss):
+    le = L.link_epoch(net, rates, split, qp, qv, backend="csr",
+                      with_loss=with_loss)
+    return le.sub_scale, le.sub_frac, le.sub_delay, le.sub_loss
+
+
+_COLUMNS = ("sub_scale", "sub_frac", "sub_delay", "sub_loss")
+
+
+def _assert_columns_equal(got, want, loose=()):
+    """Every column bit for bit, but those named in `loose` to rtol 1e-6."""
+    for name, g, w in zip(_COLUMNS, got, want):
+        if w is None:
+            assert g is None, name
+            continue
+        g, w = np.asarray(g), np.asarray(w)
+        if name in loose:
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=0, err_msg=name)
+        else:
+            np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+def _compare_columns(args, with_loss, wrap):
+    """`link_epoch`'s columns against the per-column gathers, both run
+    op by op and both jitted.  Op by op, each hop reduction reads the
+    same (h, p, n) values through the same kernel: bit for bit.  Jitted,
+    XLA:CPU emits a hop product with LLVM's `reassoc` flag, so the fused
+    loop it lands in picks the order, and a `p_loss` survival can move by
+    an ulp (seen on the k=4 fat tree in the `p_loss` thinning of
+    `sub_scale`, and under vmap in `sub_loss`, which composes `p_loss`);
+    on a net with `p_loss` those two columns are held to rtol 1e-6 there.
+    Every other column stays bitwise."""
+    fused = wrap(functools.partial(_fused_columns, with_loss=with_loss))
+    cols = wrap(functools.partial(_per_column, with_loss=with_loss))
+    want = cols(*args)
+    _assert_columns_equal(fused(*args), want)
+    loose = () if args[0].p_loss is None else ("sub_scale", "sub_loss")
+    _assert_columns_equal(jax.jit(fused)(*args), jax.jit(cols)(*args),
+                          loose=loose)
+    return want
+
+
+@pytest.mark.parametrize("loss", list(_LOSS_CASES))
+@pytest.mark.parametrize("topo", ["dumbbell_mp", "fat_tree_k4"])
+def test_link_epoch_one_take_matches_per_column_gathers(topo, loss):
+    """The one (K, n_links + 1) take `link_epoch` reduces gives every
+    column what its own public gather gives."""
+    p_loss, with_loss = _LOSS_CASES[loss]
+    net = _gather_net(topo, p_loss)
+    args = (net,) + _epoch_inputs(net, seed=23)
+    want = _compare_columns(args, with_loss, lambda f: f)
+    assert float(jnp.min(want[0])) < 1.0      # some link is overloaded
+    if with_loss:
+        assert float(jnp.max(want[3])) > 0.0  # some subflow loses bytes
+
+
+def test_link_epoch_one_take_matches_per_column_gathers_vmapped():
+    """The same under `jax.vmap` over two stacked nets, as the what-if
+    grid runs the epoch."""
+    a = _gather_net("fat_tree_k4", True, seed=1)
+    b = _gather_net("fat_tree_k4", True, seed=2)
+    b = b._replace(drain=0.8 * b.cap)
+    nets = jax.tree.map(lambda *xs: jnp.stack(xs), a, b)
+    ins = tuple(jnp.stack(xs) for xs in zip(_epoch_inputs(a, seed=3),
+                                            _epoch_inputs(b, seed=4)))
+    _compare_columns((nets,) + ins, True, jax.vmap)
+
+
+@pytest.mark.parametrize("loss", list(_LOSS_CASES))
+def test_link_epoch_gathers_the_hop_index_once(loss):
+    """One gather through the hop index per epoch, whatever the trace
+    carries (K = 3 to 5 rows): of the lowered gathers whose result has
+    the hop shape, (h, p, n) for one row or (K, h, p, n) for K, there is
+    one, of all K.  The net is a jit argument, so no row folds to a
+    constant."""
+    p_loss, with_loss = _LOSS_CASES[loss]
+    net = _gather_net("dumbbell_mp", p_loss)
+    fn = jax.jit(functools.partial(L.link_epoch, backend="csr",
+                                   with_loss=with_loss))
+    text = fn.lower(net, *_epoch_inputs(net, seed=5)).as_text()
+    n, p, h = net.routes.shape
+    hop = re.findall(rf"stablehlo\.gather.*-> tensor<(?:(\d+)x)?{h}x{p}x{n}"
+                     r"xf32>", text)
+    assert hop == [str(3 + p_loss + with_loss)], hop
 
 
 # ------------------------------------------------- path-table compression

@@ -7,11 +7,16 @@ refuse (unaligned tiles, layouts Mosaic cannot match, VMEM overflow).
 Sizes: the cross-pod payload of `chip_smoke.py` (4M f32 -> int8 + RS(8,2)
 over 8 rows) and the fat_tree_k8 / 100k-flow route tensor (1,616 links,
 8 ECMP paths, 9 hops; its PathTable holds ~58k unique 5-hop segments).
+The link epoch's hop gathers are counted at the benchmark's fat_tree_k8
+size (256 flows).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.fleetsim import links as L
 from repro.kernels import fleet_pallas, quant_pallas, rs_pallas
 
 PAYLOAD = 1 << 22               # f32 elements of the cross-pod payload
@@ -102,3 +107,69 @@ def test_fleet_kernel_compiles_fat_tree_k8(one_chip, kernel):
     }
     fn, shapes = cases[kernel]
     _compile(fn, one_chip, *shapes)
+
+
+BENCH_FLOWS = 256               # the benchmark's fat_tree_k8: one per server
+
+
+@pytest.mark.parametrize("p_loss,with_loss,batch",
+                         [(False, False, 0), (True, False, 0),
+                          (True, True, 0), (False, False, 4),
+                          (True, True, 4)])
+def test_link_epoch_one_hop_gather_fat_tree_k8(one_chip, p_loss, with_loss,
+                                               batch):
+    """The csr link epoch compiles to ONE gather through the hop index,
+    K = 3 to 5 rows at once, and none of one row; and each gathered row
+    has a hop reduction of its own.  Where one multi-output fusion
+    reduced every row of the take (lane slices of the (K, h, p, n) take,
+    K minor), the v5e epoch scan answered wrong; the barrier in
+    `links._hop_reduce_many` keeps the K reductions apart.  `batch`
+    vmaps the epoch over that many nets, as the what-if grid runs it."""
+    lead = (batch,) if batch else ()
+
+    def spec(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(lead + shape, dtype, sharding=one_chip)
+    n, p, h = BENCH_FLOWS, N_PATHS, MAX_HOPS
+    e = n * p * h                  # entries; a whole number of CSR blocks
+    link = spec((N_LINKS,))
+    lay = L.RouteLayout(
+        hop_idx=spec((h, p, fleet_pallas.pad_lanes(n)), jnp.int32),
+        path_mask=spec((n, p), jnp.bool_),
+        sort_sub=spec((e,), jnp.int32), sort_link=spec((e,), jnp.int32),
+        link_ptr=spec((N_LINKS + 2,), jnp.int32),
+        csr_gather=spec((e // L.CSR_BLOCK, L.CSR_BLOCK), jnp.int32))
+    net = L.FluidNet(cap=link, qcap=link, ecn_lo=link, ecn_hi=link,
+                     drain=link, vcap=link,
+                     use_phantom=spec((N_LINKS,), jnp.bool_),
+                     routes=spec((n, p, h), jnp.int32), dt=spec(()),
+                     layout=lay, p_loss=link if p_loss else None)
+
+    def epoch(*a):
+        return L.link_epoch(*a, backend="csr", with_loss=with_loss)
+    fn = jax.jit(jax.vmap(epoch) if batch else epoch)
+    text = fn.lower(net, spec((n,)), spec((n, p)), link,
+                    link).compile().as_text()
+    k = 3 + p_loss + with_loss
+    b = f"{batch}," if batch else ""
+    hop = re.findall(rf"= f32\[{b}(?:(\d+),)?{h},{p},{n}\]\S* gather\(",
+                     text)
+    assert hop == [str(k)], hop
+    per_comp = _hop_reductions(text, f"{b}{p},{n}")
+    assert sum(per_comp.values()) == k, per_comp
+    assert all(c == 1 for name, c in per_comp.items()
+               if name.startswith("fused_computation")), per_comp
+
+
+def _hop_reductions(text: str, dims: str) -> dict:
+    """Computation name -> its `link_gathers` reductions to a subflow
+    column of shape f32[dims], in compiled HLO text."""
+    out: dict = {}
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+        elif re.search(rf"= f32\[{dims}\]\S* reduce\(", line) and \
+                re.search(r"fleetsim\.link_gathers\)?/reduce_", line):
+            out[comp] = out.get(comp, 0) + 1
+    return out
