@@ -131,10 +131,14 @@ class Profiler:
 
     def reduce(self) -> None:
         from bench import trace as tr
+        from bench import stages
+        modules = self.run.cell["traffic"]["scan_modules"]
         try:
             path = tr.find_xplane(self.dir)
-            self.run.trace_summary = tr.summarize(
-                tr.load(path), self.run.cell["traffic"]["scan_modules"])
+            loaded = tr.load(path)
+            self.run.trace_summary = tr.summarize(loaded, modules)
+            self.run.trace_summary["op_names"] = stages.module_op_names(
+                loaded, stages.op_names(path), modules)
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
 
@@ -209,10 +213,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     entry = load_module("entries", cell["traffic"]["entry"])
     state = entry.setup(run)
     run.setup_s = time.perf_counter() - t_start
+    parts = "".join(f", {k} {v:.3f}" for k, v in run.spans.items())
     print(f"bench: set-up {run.setup_s:.3f} s: imports and devices "
-          f"{t_cell - t_start:.3f}, bundle load "
-          f"{run.spans.get('bundle_load', 0.0):.3f}, warm-up "
-          f"{run.spans.get('warmup', 0.0):.3f}", file=sys.stderr)
+          f"{t_cell - t_start:.3f}{parts}", file=sys.stderr)
     prof = Profiler(run) if trace else None
     entry.window(run, state, prof)
     device = device_info(cell["chips"])

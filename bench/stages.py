@@ -251,6 +251,19 @@ def summarize(trace: dict, names: dict, scan_modules,
             "window_s": (hi - lo) * 1e-9}
 
 
+def module_op_names(trace: dict, names: dict, scan_modules) -> dict:
+    """{op text: op_name} of the ops of the scan modules' programs, on
+    every device traced (`trace` as `bench.trace.load` gives it, `names`
+    as `op_names`)."""
+    want = tuple(f"jit_{m}(" for m in scan_modules)
+    out = {}
+    for dev, ops in names.items():
+        programs = {_program(n) for _, _, n in trace["modules"].get(dev, [])
+                    if n.startswith(want)}
+        out.update({text: op for text, (program, op) in ops.items()
+                    if program in programs})
+    return out
+
 
 def main(argv=None) -> int:
     args = sys.argv[1:] if argv is None else argv
