@@ -57,9 +57,9 @@ def program_summary(first_m: dict, change: dict, losses, b1: float) -> dict:
             "change": change}
 
 
-def _train(cell, params, batches, dot, rows=None):
-    m, opt = cell["config"]["model"], cell["config"]["optimizer"]
-    tr = cell["traffic"]
+def _train(cell, params, batches, dot, rows=None, **opt_change):
+    m, tr = cell["config"]["model"], cell["traffic"]
+    opt = dict(cell["config"]["optimizer"], **opt_change)
     out = ref.train(params, batches, tr["step0"], m, opt, dot, rows=rows)
     return {"losses": out["losses"],
             "grad": {p: np.asarray(ref.leaf(out["grad"], p), np.float32)
@@ -88,7 +88,9 @@ def reference_summary(cell: dict, seed: int, dot=ref.f32_dot,
       "half_batch"  every step on the first half of its rows only;
       "pod_local"   the exchange left out: each pod trains a copy on its
                     own rows, the reported loss is the mean of the pods'
-                    and the state the first pod's.
+                    and the state the first pod's;
+      "lr_low"      a wrong update: the learning rate 10% low;
+      "no_decay"    a wrong update: the weight decay left out.
     """
     params, tokens = seeded(cell, seed)
     rows = cell["traffic"]["rows"]
@@ -101,6 +103,12 @@ def reference_summary(cell: dict, seed: int, dot=ref.f32_dot,
                        slice(i * per, (i + 1) * per)) for i in range(pods)]
         return dict(runs[0], losses=list(np.mean(
             [r["losses"] for r in runs], axis=0)))
+    opt = cell["config"]["optimizer"]
+    if plant == "lr_low":
+        return _train(cell, params, tokens, dot,
+                      learning_rate=0.9 * opt["learning_rate"])
+    if plant == "no_decay":
+        return _train(cell, params, tokens, dot, weight_decay=0.0)
     return _train(cell, params, tokens, dot)
 
 
@@ -118,9 +126,13 @@ def stale(want: dict) -> dict:
                 change={p: 0.0 for p in want["change"]})
 
 
-def _norm_gap(got: dict, want: dict, keep) -> float:
+def _leaf_gaps(got: dict, want: dict, keep) -> dict:
     med = float(np.median([want[p] for p in keep]))
-    return max(abs(got[p] - want[p]) / max(want[p], med) for p in keep)
+    return {p: abs(got[p] - want[p]) / max(want[p], med) for p in keep}
+
+
+def _norm_gap(got: dict, want: dict, keep) -> float:
+    return max(_leaf_gaps(got, want, keep).values())
 
 
 def _norm(a) -> float:
@@ -129,13 +141,20 @@ def _norm(a) -> float:
     return float(np.linalg.norm(np.asarray(a, np.float64).ravel()))
 
 
-def numbers(got: dict, want: dict) -> dict:
-    """The four numbers of `got` (program, control or fault) against
-    `want` (the reference)."""
+def _grad_norms(got: dict, want: dict):
+    """The reference's first-gradient norm of each leaf, the leaves that
+    count (at least MIN_SHARE of the median leaf's), and `got`'s norms of
+    those."""
     gnorm = {p: _norm(g) for p, g in want["grad"].items()}
     med = float(np.median(list(gnorm.values())))
     keep = [p for p in ref.LEAVES if gnorm[p] >= MIN_SHARE * med]
-    pnorm = {p: _norm(got["grad"][p]) for p in keep}
+    return gnorm, keep, {p: _norm(got["grad"][p]) for p in keep}
+
+
+def numbers(got: dict, want: dict) -> dict:
+    """The four numbers of `got` (program, control or fault) against
+    `want` (the reference)."""
+    gnorm, keep, pnorm = _grad_norms(got, want)
     diff = sum(_norm(np.asarray(got["grad"][p], np.float64) -
                      np.asarray(want["grad"][p], np.float64)) ** 2
                for p in keep)
@@ -147,6 +166,18 @@ def numbers(got: dict, want: dict) -> dict:
         "grad_l2_err": float(np.sqrt(diff / sum(gnorm[p] ** 2
                                                 for p in keep))),
     }
+
+
+def worst_leaves(got: dict, want: dict) -> dict:
+    """The leaf that sets `grad_norm_err` and the one that sets
+    `change_norm_err`, as "/"-joined paths."""
+    gnorm, keep, pnorm = _grad_norms(got, want)
+    out = {}
+    for name, gaps in (("grad_norm_err", _leaf_gaps(pnorm, gnorm, keep)),
+                       ("change_norm_err", _leaf_gaps(
+                           got["change"], want["change"], keep))):
+        out[name] = "/".join(max(gaps, key=gaps.get))
+    return out
 
 
 def run_check(run, got: dict, want: dict) -> dict:

@@ -8,14 +8,17 @@ In one process, for each seed: the reference's first steps (float32 at
 
   program     (with --program) the cell's own set-up from the seed: the
               step compiled once, its first steps through the entry's
-              call, then freed;
+              call, then freed; `program_worst` names the leaves that
+              set its two per-leaf numbers;
   control     (first --control-seeds seeds) the reference with every
               matmul in float8 e4m3 (one scale per tensor, forward and
               backward), the nearest precision below bfloat16;
   half_batch, pod_local, altered, stale
               the faults a training cell can have (`bench/train_check.py`),
               planted in the reference put in the program's place (same
-              seeds); the last two alter the reference's own answer.
+              seeds); the last two alter the reference's own answer;
+  lr_low, no_decay
+              two wrong optimizer updates, planted the same way.
 
 One JSON line per seed.  The program needs the cell's chips; the
 control and the faults run on one.  The benchmark's own runs never run
@@ -49,10 +52,11 @@ def readings(cell: dict, seeds, program: bool, control_seeds: int,
         rec["reference_s"] = time.perf_counter() - t0
         if program:
             rec["program"] = tc.numbers(prog, want)
+            rec["program_worst"] = tc.worst_leaves(prog, want)
         if i < control_seeds:
             rec["control"] = tc.numbers(
                 tc.reference_summary(cell, seed, ref.fp8_dot), want)
-            for fault in ("half_batch", "pod_local"):
+            for fault in ("half_batch", "pod_local", "lr_low", "no_decay"):
                 rec[fault] = tc.numbers(
                     tc.reference_summary(cell, seed, plant=fault), want)
             rec["altered"] = tc.numbers(tc.altered(want), want)

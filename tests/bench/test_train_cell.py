@@ -1,7 +1,11 @@
 """The four-chip training cell at a tiny size on four forced CPU devices:
 a run is correct, the reference agrees with the program's plain psum
-step, and its readers, work counts and metric lists hold."""
+step, and its work counts, metric lists and limits hold; its readers
+read a synthetic summary and one step recorded on four TPU v5e chips."""
+import gzip
 import json
+import pathlib
+import shutil
 
 import pytest
 
@@ -122,6 +126,10 @@ def test_work_counts_at_published_widths():
 FLEET = ("flow_epochs_per_s", "setup_s")
 FLEET_LAYER = ("scan_ms_per_epoch", "gather_scatter_share",
                "device_idle_share", "window_compiles", "bundle_load_s")
+TRAIN = ("setup_s", "tokens_per_s", "mfu")
+TRAIN_LAYER = ("step_ms", "step_mfu", "dci_exchange_share", "quant_roofline",
+               "dequant_roofline", "rs_roofline", "step_idle_share",
+               "window_compiles.train")
 
 
 @pytest.mark.parametrize("workload", tiny.CELLS)
@@ -136,15 +144,29 @@ def test_train_cell_has_a_reader_for_each_metric():
     """The training cell reports only its own metrics, each with a
     reader, and each listed for it alone."""
     spec = tiny.train_cell()
-    assert [m["name"] for m in spec["end_to_end"]] == ["setup_s",
-                                                        "tokens_per_s", "mfu"]
-    assert [m["name"] for m in spec["per_layer"]] == [
-        "step_ms", "step_mfu", "dci_exchange_share", "quant_roofline",
-        "dequant_roofline", "rs_roofline", "step_idle_share",
-        "window_compiles.train"]
+    assert [m["name"] for m in spec["end_to_end"]] == list(TRAIN)
+    assert [m["name"] for m in spec["per_layer"]] == list(TRAIN_LAYER)
     for m in spec["end_to_end"][1:] + spec["per_layer"]:
         assert m["workloads"] == [tiny.TRAIN_CELL]
         harness.load_module("metrics", m["name"])
+
+
+def test_train_cell_spec_is_whole():
+    """The pending entries give the cell its four chips, a mesh of that
+    many devices whose rows split evenly over it and into the reference's
+    blocks of 2, and a bound still to be set (null) on each end-to-end
+    metric of its own (its metrics: the test above; the fat-tree cells
+    list none of them: `test_each_cell_gets_its_own_metrics`); the cell
+    and its limits file wait until a four-chip host has measured it."""
+    import math
+    spec = tiny.full_train_cell()
+    tr = spec["traffic"]
+    assert spec["chips"] == 4 == math.prod(tr["mesh"].values())
+    assert tr["rows"] % (spec["chips"] * 2) == 0
+    assert [m["bound"] for m in spec["end_to_end"][1:]] == [None, None]
+    listed = harness.load_json(harness.ROOT / "BENCHMARK.json")["workloads"]
+    assert tiny.TRAIN_CELL not in {w["name"] for w in listed}
+    assert not (harness.BENCH / "limits" / f"{tiny.TRAIN_CELL}.json").exists()
 
 
 QUANT = ("%quant_int8.4 = (s8[8912896]{0}, f32[34816]{0}) custom-call("
@@ -256,6 +278,26 @@ def test_numbers_read_the_faults_by_hand():
         pytest.approx(0.01)
 
 
+def test_worst_leaves_name_the_leaf_that_sets_each_number():
+    """`worst_leaves` names the leaf whose norm gap sets `grad_norm_err`
+    and the one whose change gap sets `change_norm_err`."""
+    import numpy as np
+    from bench import train_reference as ref
+    want = {"losses": [10.0],
+            "grad": {p: np.ones(4, np.float32) for p in ref.LEAVES},
+            "change": {p: 1.0 for p in ref.LEAVES}}
+    got = {"losses": [10.0],
+           "grad": {p: np.full(4, 1.5 if p == ("lm_head",) else 1.0,
+                               np.float32) for p in ref.LEAVES},
+           "change": {p: 0.5 if p == ("final_norm",) else 1.0
+                      for p in ref.LEAVES}}
+    assert tc.worst_leaves(got, want) == {"grad_norm_err": "lm_head",
+                                          "change_norm_err": "final_norm"}
+    nums = tc.numbers(got, want)
+    assert nums["grad_norm_err"] == pytest.approx(0.5)
+    assert nums["change_norm_err"] == pytest.approx(0.5)
+
+
 def test_stale_reads_exactly_one_on_a_large_leaf():
     """A state handed back unchanged reads exactly 1 on `grad_l2_err`
     where one leaf holds 1.2e7 values, as the output head does: the norms
@@ -272,3 +314,57 @@ def test_stale_reads_exactly_one_on_a_large_leaf():
     nums = tc.numbers(tc.stale(want), want)
     assert nums["grad_l2_err"] == 1.0
     assert nums["grad_norm_err"] == 1.0
+
+
+# One step of the cell recorded on four TPU v5e chips at 2 rows a chip
+# (8 x 2,048 tokens a step; `--trace 1`, seed
+# 3016000001), cut to that step's `bench.call` and `bench.block` spans
+# and to the chips' "XLA Modules" and "XLA Ops" lines; each op's metadata
+# keeps only its name and its `program_id` and `tf_op` stats.
+UNO_TRACE = pathlib.Path(__file__).with_name(
+    "tpu_trace_uno_step.xplane.pb.gz")
+
+
+@pytest.fixture(scope="module")
+def chip_run(tmp_path_factory):
+    """The recorded step, reduced by the harness's own `Profiler`."""
+    d = tmp_path_factory.mktemp("uno_trace")
+    with gzip.open(UNO_TRACE, "rb") as f, \
+            open(d / "step.xplane.pb", "wb") as out:
+        shutil.copyfileobj(f, out)
+    run = harness.Run(_full_cell(), tiny.SEED, 0.0)
+    run.traced_calls = [(0.0, 0.242, 16384, 1)]
+    prof = harness.Profiler(run)
+    prof.dir = str(d)
+    prof.reduce()
+    return run
+
+
+@pytest.mark.parametrize("name", ["step_mfu", "dci_exchange_share",
+                                  "quant_roofline", "dequant_roofline",
+                                  "rs_roofline", "step_idle_share"])
+def test_readers_read_the_chip_trace(chip_run, monkeypatch, name):
+    from bench import peaks
+    monkeypatch.setattr(peaks, "device_kind", lambda: "TPU v5 lite")
+    value = harness.load_module("metrics", name).read(chip_run)
+    assert 0 < value <= 100
+
+
+def test_op_name_rules_on_the_chip_trace(chip_run):
+    """On each chip the step ran 4 `quant_int8`, 4 `dequant_int8` and 8
+    `gf_matmul` kernels (RS encode and decode of 4 chunks), each inside
+    the exchange's `shard_map`; no op of the model's forward or backward
+    pass lies there."""
+    import re
+    s = chip_run.trace_summary
+    names = s["op_names"]
+    for kernel, n in (("quant_int8", 4), ("dequant_int8", 4),
+                      ("gf_matmul", 8)):
+        ops = [k for k in s["scan_ops"] if re.match(rf"^%{kernel}\.", k)]
+        assert len(ops) == n
+        assert {names[k] for k in ops} == \
+            {f"jit(step)/shard_map/jit({kernel})/pallas_call"}
+    exchange = [n for n in names.values() if "/shard_map/" in n]
+    assert exchange and not any("jvp(" in n for n in exchange)
+    assert harness.load_module("metrics", "step_ms").read(chip_run) == \
+        pytest.approx(236.6, rel=1e-3)

@@ -55,11 +55,12 @@ TINY_TRAIN = {"rows": 4, "seq": 64, "pool": 4}
 # Limits for this tiny size on the CPU, set as the chip's are to be: the
 # program's worst of 7 seeds (loss 2.57e-4, grad norm 2.0e-3, change
 # 2.5e-3, gradient 1.92e-2) against the float8 control's best of 4
-# (8.35e-4, 1.17e-2, 7.1e-3 (under 3x: the stale state's 1 instead),
-# 0.223), two thirds of the way up in log scale.
+# (8.35e-4, 1.17e-2, -, 0.223) and, on the change, the learning rate
+# 10% low (0.108 on 4 seeds; the control's 4.6e-3 is under 3x), two
+# thirds of the way up in log scale.
 TINY_LIMITS = {"loss_err": {"limit": 5.5e-4},
                "grad_norm_err": {"limit": 6e-3},
-               "change_norm_err": {"limit": 0.1},
+               "change_norm_err": {"limit": 0.03},
                "grad_l2_err": {"limit": 0.1}}
 
 
